@@ -45,6 +45,9 @@ def main() -> None:
     ap.add_argument("--out", default="experiments/bench")
     args = ap.parse_args()
 
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     if args.smoke:
         args.fast = True
     # --only can name any module (also under --smoke, which then just

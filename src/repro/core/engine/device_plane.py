@@ -22,6 +22,17 @@ Structural requirements (anything else returns None — host path):
   reassign blocks mid-run), and no offloaded eval service in the loop
   (``accel_eval="worker"`` keeps the host loop).
 
+Where the plane runs: thread-backend workers share the process that holds
+the accelerator, so their resident blocks live on it (a TPU when there is
+one).  Process-backend workers are host workers: each child pins its JAX to
+the CPU platform before it builds anything, because a chip belongs to one
+process, so their resident blocks live in host memory.
+
+``"pallas"`` compiles the Mosaic kernels and never falls back to jnp or to
+interpret mode: a float64 iterate raises ``ValueError`` (Mosaic lowers no
+64-bit types) and value iteration raises ``NotImplementedError`` (Mosaic
+lowers no general gather); see :mod:`repro.kernels.ops`.
+
 ``"auto"`` (the default) additionally requires ``n >= AUTO_THRESHOLD``:
 below it the halo savings don't pay for the host<->device hops, above it
 the O(n) snapshot per dispatch is the dominant cost the plane removes.

@@ -67,6 +67,7 @@ from __future__ import annotations
 import atexit
 import os
 import queue as queue_mod
+import sys
 import time
 from collections import deque
 from multiprocessing import get_context, shared_memory
@@ -160,8 +161,10 @@ def _worker_main(
 
     Messages out (``result_q``): ``(w, kind, data, snap_wu)`` with kind in
     {"boot", "ready", "ok", "crash", "rejoin", "eval_ok", "eval_crash",
-    "tel", "error"}; for "ok" the values are in the shared result slot and
-    ``data`` is their length; for "eval_ok" the full-map result is in the
+    "tel", "error"}; "boot" carries the platform the worker's JAX runs on
+    ("cpu", or None for a worker that never imported JAX); for "ok" the
+    values are in the shared result slot and ``data`` is their length;
+    for "eval_ok" the full-map result is in the
     slot (``data`` = its length) or ``data`` is the residual-norm scalar.
     With ``cfg.telemetry`` set, the worker times its own evaluations with
     a local ``perf_counter`` and ships them as ``("tel", [(age_s, dur_s,
@@ -175,6 +178,12 @@ def _worker_main(
     parent counts the restart when the downtime *ends*, the same
     convention as every other backend.
     """
+    # Host worker: the accelerator belongs to the parent process, and a
+    # second process cannot hold the chip, so every child runs JAX on the
+    # CPU platform, set before the problem builds anything.
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    if "jax" in sys.modules:  # imported while unpickling the payload
+        sys.modules["jax"].config.update("jax_platforms", "cpu")
     shm = slot = None
     try:
         problem = rebuild_problem(payload)
@@ -182,7 +191,10 @@ def _worker_main(
         slot = _attach_shm(slot_name)
         view = np.ndarray(n + 1, dtype=np.float64, buffer=shm.buf)
         slot_view = np.ndarray(n, dtype=np.float64, buffer=slot.buf)
-        result_q.put((w, "boot", None, 0))
+        jax_mod = sys.modules.get("jax")
+        result_q.put((w, "boot",
+                      None if jax_mod is None else jax_mod.default_backend(),
+                      0))
         cfg = prof = rng = my_block = dplan = my_read = None
         tel_buf: List[Tuple[float, float, str]] = []  # (end_perf, dur, kind)
         tel_bs = 0  # telemetry batch size; 0 = telemetry off
@@ -382,7 +394,8 @@ class _WorkerPool:
         try:
             for p in self.procs:
                 p.start()
-            self._await(self.n_workers, {"boot"})
+            boot = self._await(self.n_workers, {"boot"})
+            self.platforms = [boot[w] for w in range(self.n_workers)]
         except Exception:
             self.close()  # don't leak half-booted interpreters / segments
             raise
@@ -415,9 +428,11 @@ class _WorkerPool:
         self._await(self.n_workers, {"ready"})
         self.runs_served += 1
 
-    def _await(self, count: int, kinds: Set[str]) -> None:
+    def _await(self, count: int, kinds: Set[str]) -> Dict[int, object]:
+        """Wait for ``count`` workers' ``kinds`` messages; returns each
+        worker's message data."""
         deadline = time.monotonic() + _READY_TIMEOUT_S
-        seen: Set[int] = set()
+        seen: Dict[int, object] = {}
         while len(seen) < count:
             w, kind, data, _ = self.get_result(deadline)
             if kind == "error":
@@ -425,7 +440,8 @@ class _WorkerPool:
             if kind == "tel":
                 continue  # stray telemetry batch from a stopped run
             assert kind in kinds, f"unexpected pre-run message {kind!r}"
-            seen.add(w)
+            seen[w] = data
+        return seen
 
     def get_result(self, deadline: float):
         """Blocking result read that notices dead children and timeouts."""
@@ -560,7 +576,8 @@ class process_pools:
 def pool_stats() -> Dict[Tuple[str, int, str], Dict[str, object]]:
     """Live pool inventory: pids, runs served and leases, per pool key."""
     return {
-        key: {"pids": pool.pids(), "runs_served": pool.runs_served,
+        key: {"pids": pool.pids(), "platforms": list(pool.platforms),
+              "runs_served": pool.runs_served,
               "n_workers": pool.n_workers, "healthy": pool.healthy(),
               "leases": _POOLS.lease_count(key)}
         for key, pool in _POOLS.items()

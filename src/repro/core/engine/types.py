@@ -230,7 +230,9 @@ class RunConfig:
     #                  projection, no scenario/controller/trace); otherwise
     #                  identical to "off"
     #   "jnp"/"on"   — force the fused jitted-jnp device step
-    #   "pallas"     — force the fused Pallas kernels (TPU lowering)
+    #   "pallas"     — force the fused Pallas kernels, compiled by Mosaic;
+    #                  raises for a float64 iterate (Mosaic lowers no 64-bit
+    #                  types) and for value iteration (no general gather)
     #   "interpret"  — force the Pallas kernels in interpret mode (CPU
     #                  validation of the exact kernel bodies; slow)
     # The virtual backend always ignores this knob — fixed-seed virtual

@@ -1,14 +1,17 @@
-"""Pallas TPU Bellman operator (the paper's §3.3.2 hot loop).
+"""Pallas Bellman operator (the paper's §3.3.2 hot loop).
 
 (T V)(s) = max_a [ R(s,a) + gamma * sum_b P_b(s,a) * V(idx_b(s,a)) ]
 
-TPU adaptation: the state axis is blocked (grid over S/bs); the full value
-vector V stays resident in VMEM across the sweep (Garnet state spaces are
-small: |S| <= a few thousand doubles), so each block performs a VMEM gather
-of its (bs, A, b) successor values followed by a VPU expectation + max
-reduction.  The gather runs on the VPU from VMEM — validated in interpret
-mode; on hardware the per-(s,a) fan-in b is small and contiguous enough to
-lower to dynamic-slice loads.
+The state axis is blocked (grid over S/bs); the value vector V is resident
+in VMEM and each block gathers its (bs, A, b) successor values, then takes
+the expectation and the max over actions.
+
+These kernels run in interpret mode only.  Mosaic (JAX 0.9, libtpu 0.0.34)
+lowers no general gather: ``V[idx]`` with a 1-D V is refused with "Only 2D
+gather is supported", and its 2-D gather requires the indices to have the
+operand's own shape, which a Garnet successor table (arbitrary states of
+S = 2**20) does not.  ``repro.kernels.ops`` raises ``NotImplementedError``
+instead of compiling them.
 """
 
 from __future__ import annotations
@@ -18,9 +21,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-f32 = jnp.float32
-
 
 def _bellman_kernel(idx_ref, probs_ref, r_ref, v_ref, o_ref, *, gamma: float):
     idx = idx_ref[...]  # (bs, A, b) int32
@@ -49,7 +49,7 @@ def _bellman_block_kernel(idx_ref, probs_ref, r_ref, v_ref, vold_ref,
 @functools.partial(jax.jit, static_argnames=("gamma", "interpret"))
 def bellman_block(idx: jax.Array, probs: jax.Array, rewards: jax.Array,
                   v: jax.Array, v_old: jax.Array, *, gamma: float,
-                  interpret: bool = True):
+                  interpret: bool):
     """One Bellman backup for a block of ``rows`` states, fused with its
     block-local residual.
 
@@ -70,8 +70,8 @@ def bellman_block(idx: jax.Array, probs: jax.Array, rewards: jax.Array,
 
 @functools.partial(jax.jit, static_argnames=("gamma", "block_s", "interpret"))
 def bellman(idx: jax.Array, probs: jax.Array, rewards: jax.Array,
-            v: jax.Array, *, gamma: float, block_s: int = 128,
-            interpret: bool = True) -> jax.Array:
+            v: jax.Array, *, gamma: float, interpret: bool,
+            block_s: int = 128) -> jax.Array:
     S, A, b = idx.shape
     bs = min(block_s, S)
     while S % bs:

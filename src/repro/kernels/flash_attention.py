@@ -100,13 +100,13 @@ def flash_attention(
     k: jax.Array,  # (B, Skv, nkv, hd)
     v: jax.Array,  # (B, Skv, nkv, hd)
     *,
+    interpret: bool,
     causal: bool = True,
     window: Optional[int] = None,
     softcap: Optional[float] = None,
     q_offset: int = 0,
     block_q: int = 128,
     block_kv: int = 128,
-    interpret: bool = True,
 ) -> jax.Array:
     B, Sq, nq, hd = q.shape
     _, Skv, nkv, _ = k.shape

@@ -22,15 +22,18 @@ the *full* SCF map on the stale snapshot and return only their rows (paper
 §3.3.3) — evaluation-level perturbation, coupling density 1.  The
 coordinator symmetrizes after every application (``project``) and uses the
 DIIS commutator residual ``[F(P), P]`` for acceleration and convergence.
+
+The algebra is float64 numpy on the host, whatever JAX's backend: a Fock
+matrix of a few dozen sites gives an accelerator no work, and XLA's
+float64 ``eigh`` on a TPU v5e is only float32-accurate (an eigen-residual
+of 1.3e-6 on a 20 x 20 matrix), which stalls the commutator above a 1e-6
+tolerance.
 """
 
 from __future__ import annotations
 
-import functools
 from typing import List, Optional
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core.fixedpoint import FixedPointProblem, restrict
@@ -53,43 +56,37 @@ class PPPChain:
             H[i, i + 1] = H[i + 1, i] = -t
         R = np.abs(np.arange(n_atoms)[:, None] - np.arange(n_atoms)[None, :])
         gamma = U / np.sqrt(1.0 + (U * R) ** 2)  # Ohno
-        self.H = jnp.asarray(H)
-        self.gamma = jnp.asarray(gamma)
+        self.H = H
+        self.gamma = gamma
         # Nuclear(core)-core repulsion of the +1 cores, constant shift.
-        self.e_core = float(np.sum(np.triu(np.asarray(gamma), k=1)))
+        self.e_core = float(np.sum(np.triu(gamma, k=1)))
 
     # ------------------------------------------------------------------ #
-    @functools.partial(jax.jit, static_argnums=0)
-    def fock(self, P: jnp.ndarray) -> jnp.ndarray:
-        J = jnp.diag(self.gamma @ jnp.diag(P))
+    def fock(self, P: np.ndarray) -> np.ndarray:
+        J = np.diag(self.gamma @ np.diag(P))
         K = P * self.gamma
         return self.H + J - 0.5 * K
 
-    @functools.partial(jax.jit, static_argnums=0)
-    def scf_map(self, P: jnp.ndarray) -> jnp.ndarray:
+    def scf_map(self, P: np.ndarray) -> np.ndarray:
         F = self.fock(P)
-        _, C = jnp.linalg.eigh(F)
+        _, C = np.linalg.eigh(F)
         Cocc = C[:, : self.n_occ]
         return 2.0 * Cocc @ Cocc.T
 
-    @functools.partial(jax.jit, static_argnums=0)
-    def commutator(self, P: jnp.ndarray) -> jnp.ndarray:
+    def commutator(self, P: np.ndarray) -> np.ndarray:
         F = self.fock(P)
         return F @ P - P @ F  # S = I
 
-    @functools.partial(jax.jit, static_argnums=0)
-    def electronic_energy(self, P: jnp.ndarray) -> jnp.ndarray:
+    def electronic_energy(self, P: np.ndarray) -> float:
         F = self.fock(P)
-        return 0.5 * jnp.sum(P * (self.H + F))
+        return float(0.5 * np.sum(P * (self.H + F)))
 
     def energy(self, P: np.ndarray) -> float:
-        Pm = jnp.asarray(P.reshape(self.n, self.n))
-        return float(self.electronic_energy(Pm)) + self.e_core
+        return self.electronic_energy(P.reshape(self.n, self.n)) + self.e_core
 
     def core_guess(self) -> np.ndarray:
-        _, C = jnp.linalg.eigh(self.H)
-        Cocc = C[:, : self.n_occ]
-        return np.asarray(2.0 * Cocc @ Cocc.T)
+        """Density of the core Hamiltonian (F(0) = H)."""
+        return self.scf_map(np.zeros_like(self.H))
 
 
 class UHFPPP:
@@ -107,33 +104,28 @@ class UHFPPP:
         self.n = chain.n
         self.n_occ = chain.n // 2  # S_z = 0: n/2 up + n/2 down electrons
 
-    @functools.partial(jax.jit, static_argnums=0)
-    def fock(self, Pu: jnp.ndarray, Pd: jnp.ndarray):
+    def fock(self, Pu: np.ndarray, Pd: np.ndarray):
         c = self.chain
-        J = jnp.diag(c.gamma @ jnp.diag(Pu + Pd))
+        J = np.diag(c.gamma @ np.diag(Pu + Pd))
         return c.H + J - Pu * c.gamma, c.H + J - Pd * c.gamma
 
-    @functools.partial(jax.jit, static_argnums=0)
-    def scf_map(self, Pu: jnp.ndarray, Pd: jnp.ndarray):
+    def scf_map(self, Pu: np.ndarray, Pd: np.ndarray):
         Fu, Fd = self.fock(Pu, Pd)
-        _, Cu = jnp.linalg.eigh(Fu)
-        _, Cd = jnp.linalg.eigh(Fd)
+        _, Cu = np.linalg.eigh(Fu)
+        _, Cd = np.linalg.eigh(Fd)
         Pu2 = Cu[:, : self.n_occ] @ Cu[:, : self.n_occ].T
         Pd2 = Cd[:, : self.n_occ] @ Cd[:, : self.n_occ].T
         return Pu2, Pd2
 
-    @functools.partial(jax.jit, static_argnums=0)
     def commutator(self, Pu, Pd):
         Fu, Fd = self.fock(Pu, Pd)
         return Fu @ Pu - Pu @ Fu, Fd @ Pd - Pd @ Fd
 
     def energy(self, Pu: np.ndarray, Pd: np.ndarray) -> float:
         c = self.chain
-        Pu = jnp.asarray(Pu)
-        Pd = jnp.asarray(Pd)
         Fu, Fd = self.fock(Pu, Pd)
-        e = 0.5 * (jnp.sum((Pu + Pd) * c.H) + jnp.sum(Pu * Fu)
-                   + jnp.sum(Pd * Fd))
+        e = 0.5 * (np.sum((Pu + Pd) * c.H) + np.sum(Pu * Fu)
+                   + np.sum(Pd * Fd))
         return float(e) + c.e_core
 
 
@@ -164,11 +156,10 @@ class UHFSCFProblem(FixedPointProblem):
 
     def _split(self, x: np.ndarray):
         n = self.n_ao
-        return (jnp.asarray(x[: n * n].reshape(n, n)),
-                jnp.asarray(x[n * n:].reshape(n, n)))
+        return x[: n * n].reshape(n, n), x[n * n:].reshape(n, n)
 
     def initial(self) -> np.ndarray:
-        P = np.asarray(self.chain.core_guess()) / 2.0
+        P = self.chain.core_guess() / 2.0
         alt = np.diag(0.5 * self.spin_seed * (-1.0) ** np.arange(self.n_ao))
         Pu, Pd = P + alt, P - alt
         return np.concatenate([Pu.reshape(-1), Pd.reshape(-1)])
@@ -176,8 +167,7 @@ class UHFSCFProblem(FixedPointProblem):
     def full_map(self, x: np.ndarray) -> np.ndarray:
         Pu, Pd = self._split(x)
         Pu2, Pd2 = self.uhf.scf_map(Pu, Pd)
-        return np.concatenate([np.asarray(Pu2).reshape(-1),
-                               np.asarray(Pd2).reshape(-1)])
+        return np.concatenate([Pu2.reshape(-1), Pd2.reshape(-1)])
 
     def block_update(self, x: np.ndarray, indices: np.ndarray) -> np.ndarray:
         # Spin blocks concatenate two flat ranges, so a single slice rarely
@@ -197,14 +187,12 @@ class UHFSCFProblem(FixedPointProblem):
         Pu, Pd = self._split(x)
         Pu = 0.5 * (Pu + Pu.T)
         Pd = 0.5 * (Pd + Pd.T)
-        return np.concatenate([np.asarray(Pu).reshape(-1),
-                               np.asarray(Pd).reshape(-1)])
+        return np.concatenate([Pu.reshape(-1), Pd.reshape(-1)])
 
     def accel_residual(self, x: np.ndarray, g: np.ndarray) -> np.ndarray:
         Pu, Pd = self._split(x)
         Cu, Cd = self.uhf.commutator(Pu, Pd)
-        return np.concatenate([np.asarray(Cu).reshape(-1),
-                               np.asarray(Cd).reshape(-1)])
+        return np.concatenate([Cu.reshape(-1), Cd.reshape(-1)])
 
     def residual(self, x: np.ndarray) -> np.ndarray:
         return self.accel_residual(x, x)
@@ -214,7 +202,7 @@ class UHFSCFProblem(FixedPointProblem):
 
     def energy(self, x: np.ndarray) -> float:
         Pu, Pd = self._split(x)
-        return self.uhf.energy(np.asarray(Pu), np.asarray(Pd))
+        return self.uhf.energy(Pu, Pd)
 
     def dependency_counts(self) -> None:
         return None  # dense coupling
@@ -260,8 +248,7 @@ class SCFProblem(FixedPointProblem):
         return np.asarray(P0).reshape(-1).astype(np.float64)
 
     def full_map(self, x: np.ndarray) -> np.ndarray:
-        P = jnp.asarray(x.reshape(self.n_ao, self.n_ao))
-        return np.asarray(self.chain.scf_map(P)).reshape(-1)
+        return self.chain.scf_map(x.reshape(self.n_ao, self.n_ao)).reshape(-1)
 
     def block_update(self, x: np.ndarray, indices: np.ndarray) -> np.ndarray:
         # Worker: full SCF map on the stale snapshot, return owned rows only
@@ -284,12 +271,12 @@ class SCFProblem(FixedPointProblem):
     # ----------------------------------------------------------------- #
     def accel_residual(self, x: np.ndarray, g: np.ndarray) -> np.ndarray:
         """DIIS commutator error FPS - SPF (S = I) at the current iterate."""
-        P = jnp.asarray(x.reshape(self.n_ao, self.n_ao))
-        return np.asarray(self.chain.commutator(P)).reshape(-1)
+        P = x.reshape(self.n_ao, self.n_ao)
+        return self.chain.commutator(P).reshape(-1)
 
     def residual(self, x: np.ndarray) -> np.ndarray:
-        P = jnp.asarray(x.reshape(self.n_ao, self.n_ao))
-        return np.asarray(self.chain.commutator(P)).reshape(-1)
+        P = x.reshape(self.n_ao, self.n_ao)
+        return self.chain.commutator(P).reshape(-1)
 
     def residual_norm(self, x: np.ndarray) -> float:
         return float(np.linalg.norm(self.residual(x)))

@@ -212,6 +212,46 @@ class _VIDevicePlan(DeviceBlockPlan):
         return np.asarray(tv), float(norm)
 
 
+def _policy_iteration(idx, probs, R, gamma: float,
+                      tol: float = 1e-13) -> np.ndarray:
+    """V* of a finite MDP by policy iteration, in numpy on the host.
+
+    Each policy is evaluated by iterating ``V <- r_pi + gamma P_pi V`` on
+    its sparse transition matrix (one action's successors: a quarter of a
+    Bellman sweep's gathers, and no max) from the previous policy's values,
+    to a sup-norm step below ``tol``.  A state switches action only when
+    that gains more than ``2 tol / (1 - gamma)``, twice what the evaluation
+    error can fake, so every switch truly improves and the loop ends in a
+    few policies.  Value iteration from zero needs log(tol) / log(gamma)
+    full Bellman sweeps (about 600 at gamma = 0.95): minutes at S = 2**20
+    on a TPU, whose gathers are slow.
+    """
+    import scipy.sparse as sp
+
+    S, _, b = idx.shape
+    states = np.arange(S)
+    rows = np.repeat(states, b)
+    gain = 2.0 * tol / (1.0 - gamma)
+    pi = np.zeros(S, dtype=np.int64)
+    V = np.zeros(S)
+    while True:
+        P = sp.csr_matrix((probs[states, pi].ravel(),
+                           (rows, idx[states, pi].ravel())), shape=(S, S))
+        r = R[states, pi]
+        for _ in range(200_000):
+            V2 = r + gamma * (P @ V)
+            done = np.max(np.abs(V2 - V)) < tol
+            V = V2
+            if done:
+                break
+        q = R + gamma * np.einsum("sab,sab->sa", probs, V[idx])
+        best = np.argmax(q, axis=1)
+        better = q[states, best] > q[states, pi] + gain
+        if not better.any():
+            return V
+        pi = np.where(better, best, pi)
+
+
 def _rebuild_vi(mdp_cls, mdp_kwargs):
     """Factory for multi-interpreter executors (see ``factory_spec``)."""
     return ValueIterationProblem(mdp_cls(**mdp_kwargs))
@@ -247,14 +287,10 @@ class ValueIterationProblem(FixedPointProblem):
 
     def exact_solution(self) -> np.ndarray:
         if self._sol is None:
-            V = np.zeros(self.n)
-            for _ in range(200_000):
-                V2 = self.full_map(V)
-                if np.max(np.abs(V2 - V)) < 1e-13:
-                    V = V2
-                    break
-                V = V2
-            self._sol = V
+            mdp = self.mdp
+            self._sol = _policy_iteration(
+                np.asarray(mdp.idx), np.asarray(mdp.probs),
+                np.asarray(mdp.R), mdp.gamma)
         return self._sol
 
     def device_block_plan(self, indices, mode: str):

@@ -204,14 +204,22 @@ class TestBackendParity:
         prob = JacobiProblem(grid=8, sweeps=5)
         tol = 1e-6
         kw = {"compute_time": 1e-3} if backend == "virtual" else {}
-        r = run_fixed_point(prob, RunConfig(
-            mode="async", executor=backend, n_workers=2, tol=tol,
-            max_updates=10**5, **kw))
+        cfg = RunConfig(mode="async", executor=backend, n_workers=2, tol=tol,
+                        max_updates=10**5, **kw)
+        r = run_fixed_point(prob, cfg)
         assert r.converged
         assert prob.residual_norm(r.x) < tol
         # All backends land on the same fixed point (error scale set by the
         # Laplacian's conditioning, not by scheduling nondeterminism).
         assert r.error_norm < 1e-3
+        if backend == "process":
+            # Process workers are host workers: their JAX is pinned to the
+            # CPU platform, whatever accelerator the parent holds.
+            from repro.core.engine.poolreg import payload_key
+            from repro.core.engine.process import pool_stats, problem_payload
+
+            key = payload_key(problem_payload(prob), cfg)
+            assert pool_stats()[key]["platforms"] == ["cpu", "cpu"]
 
     @pytest.mark.parametrize("backend", backend_params())
     def test_value_iteration_parity(self, backend):

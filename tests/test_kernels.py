@@ -86,15 +86,20 @@ class TestFlashAttention:
 # jacobi stencil
 # --------------------------------------------------------------------- #
 class TestJacobiStencil:
-    @pytest.mark.parametrize("g", [8, 16, 32, 100])
-    @pytest.mark.parametrize("block_rows", [2, 4, 8, 16])
-    def test_matches_reference(self, g, block_rows):
-        x = jnp.asarray(RNG.standard_normal(g * g), jnp.float32)
-        b = jnp.asarray(RNG.standard_normal(g * g), jnp.float32)
-        out = ops.jacobi_sweep(x, b, g, block_rows=block_rows)
+    @pytest.mark.parametrize("g", [8, 16, 32, 100,
+                                   384,   # float64: 2 row tiles, last padded
+                                   600])  # both dtypes: >= 2 tiles, last padded
+    @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-6),
+                                           (jnp.float64, 1e-14)])
+    def test_matches_reference(self, g, dtype, tol):
+        jax.config.update("jax_enable_x64", True)
+        x = jnp.asarray(RNG.standard_normal(g * g), dtype)
+        b = jnp.asarray(RNG.standard_normal(g * g), dtype)
+        out = ops.jacobi_sweep(x, b, g)
         want = ref.ref_jacobi_sweep(x, b, g)
+        assert out.dtype == dtype
         np.testing.assert_allclose(np.asarray(out), np.asarray(want),
-                                   rtol=1e-6, atol=1e-6)
+                                   rtol=tol, atol=tol)
 
     def test_float64(self):
         jax.config.update("jax_enable_x64", True)
@@ -205,7 +210,7 @@ class TestAndersonMixKernel:
     @pytest.mark.parametrize("dtype,rtol", [(jnp.float32, 2e-5),
                                             (jnp.float64, 1e-13)])
     @pytest.mark.parametrize("N,block_n", [
-        (1000, 256),   # N % block_n != 0: bn must shrink to a divisor
+        (1000, 256),   # N % block_n != 0: the last block is padded
         (4096, 4096),  # single block
         (513, 128),    # prime-ish N: worst-case divisor search
     ])
@@ -264,6 +269,34 @@ class TestJacobiHaloKernel:
         top = RNG.standard_normal(g)
         bot = RNG.standard_normal(g)
         bg = RNG.standard_normal((rows, g))
+        out, norm = ops.jacobi_halo_sweeps(
+            jnp.asarray(blk), jnp.asarray(top), jnp.asarray(bot),
+            jnp.asarray(bg), sweeps=sweeps, interpret=True)
+        want, wnorm = ref.ref_jacobi_halo_sweeps(blk, top, bot, bg,
+                                                 sweeps=sweeps)
+        np.testing.assert_array_equal(np.asarray(out), want)
+        np.testing.assert_allclose(float(norm), wnorm, rtol=1e-12)
+
+    @pytest.mark.parametrize("rows,g,sweeps", [
+        (256, 1024, 10),  # 2 tiles, 16-row ghost bands
+        (300, 1024, 3),   # 3 tiles, the last padded
+        (200, 2048, 8),   # 4 tiles, ghost band exactly as deep as the sweeps
+        (1365, 128, 10),  # 2 tiles, the last padded (3 workers' block rows)
+        (129, 8192, 1),   # 9 tiles, the last holding one row
+        (40, 16384, 2),   # 5 tiles of one ghost band each
+    ])
+    def test_row_tiles_match_numpy_reference(self, rows, g, sweeps):
+        """Gridded over row tiles of float64 blocks above the tile budget,
+        the ghost bands keep every tile exact, and the padding past the
+        block (NaN in interpret mode) stays out of the values and the norm:
+        values bitwise equal to the whole-block oracle."""
+        from repro.kernels.jacobi_stencil import row_tiling
+
+        jax.config.update("jax_enable_x64", True)
+        tile, halo = row_tiling(rows, g, 8, sweeps)
+        assert -(-rows // tile) > 1 and halo >= sweeps
+        blk, bg = RNG.standard_normal((2, rows, g))
+        top, bot = RNG.standard_normal((2, g))
         out, norm = ops.jacobi_halo_sweeps(
             jnp.asarray(blk), jnp.asarray(top), jnp.asarray(bot),
             jnp.asarray(bg), sweeps=sweeps, interpret=True)
