@@ -1346,15 +1346,21 @@ class Coordinator:
 
     def record(self, t: float) -> float:
         tel = self.telemetry
-        t_h0 = time.perf_counter() if tel is not None else 0.0
+        if tel is not None:
+            # The span times the residual evaluation on the recorder's
+            # clock (zero on the virtual clock, which charges it nothing);
+            # history and series keep the caller's t.
+            t_h0 = time.perf_counter()
+            sec = tel.section("record", "coord").open()
         self.res_norm = self.problem.residual_norm(self.x)
+        if tel is not None:
+            sec.close(res=self.res_norm, wu=self.wu)
+            tel.host_busy_s += time.perf_counter() - t_h0
         self._res_version = self._x_version
         self.history.append((t, self.wu, self.res_norm))
         if self.tracer is not None:
             self.tracer.record(t, self.res_norm)
         if tel is not None:
-            tel.host_busy_s += time.perf_counter() - t_h0
-            tel.span("record", "coord", t, t, res=self.res_norm, wu=self.wu)
             tel.series_point("residual", t, self.res_norm)
             tel.maybe_sample_busy(t, self.busy_s)
         return self.res_norm
@@ -1379,7 +1385,9 @@ class Coordinator:
             self.tracer.record(plan.t, self.res_norm)
         if self.telemetry is not None:
             tel = self.telemetry
-            tel.span("record", "coord", plan.t, plan.t,
+            # Begin to commit: the plan's evaluation ran on another thread,
+            # so no annotation can cover it.
+            tel.span("record", "coord", plan.t, tel.now(),
                      res=self.res_norm, wu=plan.wu, offloaded=offloaded)
             tel.series_point("residual", plan.t, self.res_norm)
         return self.res_norm

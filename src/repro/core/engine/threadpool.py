@@ -75,10 +75,18 @@ class ThreadPoolExecutor(Executor):
         # the clock starts, so compile time doesn't skew wall-clock.  The
         # coordinator's memoized partition is passed through so exactly the
         # dispatched block objects get warmed.
+        tel = coord.telemetry
+        if tel is not None:
+            # Timed on the recorder's host clock; the loop's install_clock
+            # re-bases it onto the run's clock, where it ends before the
+            # loop starts.
+            sec = tel.section("warm", "coord").open()
         warm_problem(problem, cfg, blocks=coord.blocks)
         if cfg.accel is not None:
             problem.full_map(coord.x)
         problem.residual_norm(coord.x)
+        if tel is not None:
+            sec.close()
         if cfg.capture_trace and cfg.mode == "async":
             from ...chaos.trace import TraceRecorder
 
@@ -109,11 +117,22 @@ class ThreadPoolExecutor(Executor):
     def _sync_task(
         problem: FixedPointProblem, cfg: RunConfig, x_snap: np.ndarray,
         idx: np.ndarray, delay: float, crashed: bool,
-        profile: FaultProfile,
+        profile: FaultProfile, tel=None, task: Optional[int] = None,
+        worker: int = 0,
     ) -> Optional[np.ndarray]:
+        if tel is not None:
+            lane = f"w{worker}"
+            sec = tel.section("block_eval", lane, task=task,
+                              path="host").open()
         vals = worker_eval(problem, cfg, x_snap, idx)
+        if tel is not None:
+            sec.close()
         if delay > 0.0:
+            if tel is not None:
+                sec = tel.section("delay", lane, task=task).open()
             time.sleep(delay)
+            if tel is not None:
+                sec.close()
         if crashed:
             # BSP: the barrier stalls until the worker restarts; its
             # in-flight result is lost either way.
@@ -139,16 +158,20 @@ class ThreadPoolExecutor(Executor):
                 x_snap = coord.x.copy()
                 rs = time.perf_counter() - t0
                 plans = coord.plan_round(alive, coord.select_round_indices())
-                futs = [
-                    pool.submit(self._sync_task, problem, cfg, x_snap, idx,
-                                delay, crashed, prof)
-                    for _, prof, idx, delay, crashed in plans
-                ]
+                futs = []
+                for w, prof, idx, delay, crashed in plans:
+                    tid = None
+                    if tel is not None:
+                        # The task spans the round: open at its start.
+                        tid = tel.task_id()
+                        tel.task_open(w, rs, task=tid)
+                    futs.append(pool.submit(
+                        self._sync_task, problem, cfg, x_snap, idx, delay,
+                        crashed, prof, tel, tid, w))
                 for (w, prof, idx, _, crashed), fut in zip(plans, futs):
                     vals = fut.result()
                     coord.arrivals += 1
                     if tel is not None:
-                        tel.task_open(w, rs)
                         tel.task_close(
                             w, disp="crash" if crashed else "applied")
                     if crashed:
@@ -227,12 +250,16 @@ class ThreadPoolExecutor(Executor):
                 if dp is not None:
                     dplans[dw] = dp
             if dplans:
-                # Warm the fused-kernel specializations before the clock
-                # starts (mirrors warm_problem for the host path).
+                # Warm the fused-kernel specializations before the workers
+                # start (mirrors warm_problem for the host path).
+                if tel is not None:
+                    sec = tel.section("warm", "coord").open()
                 zx = np.zeros(problem.n)
                 for dw, dp in dplans.items():
                     dp.refresh(zx[coord.blocks[dw]])
                     dp.step(*[zx[s] for s in dp.needs])
+                if tel is not None:
+                    sec.close()
 
         def worker_loop(w: int) -> None:
             prof = _fault_for(cfg, w)
@@ -240,8 +267,19 @@ class ThreadPoolExecutor(Executor):
             dp = dplans.get(w)
             dev_fresh = False  # resident block mirrors x[block]?
             dev_cver = -1  # commit_version at the last freshness grant
+            blk_vals = None  # block re-shipped this task (device plane)
+            if tel is not None:
+                lane = f"w{w}"
             while not stop.is_set():
+                if tel is not None:
+                    # Every section of this task carries its id; the lock
+                    # waits are spans only (an annotation would claim the
+                    # holder's time).
+                    tid = tel.task_id()
+                    t_wait = elapsed()
                 with lock, coord.busy():
+                    if tel is not None:
+                        t_held = elapsed()
                     if stop.is_set():
                         return
                     if not coord.dispatchable(w):
@@ -252,7 +290,9 @@ class ThreadPoolExecutor(Executor):
                     launch_wu = coord.wu
                     idx = coord.select_indices(w)
                     if tel is not None:
-                        tel.task_open(w, elapsed())
+                        tel.span("lock_wait", lane, t_wait, t_held, task=tid,
+                                 phase="dispatch")
+                        tel.task_open(w, elapsed(), task=tid)
                     if dp is not None:
                         # Fresh resident block: ship only the halo slices
                         # (O(needs)); stale: re-ship the block (O(block)).
@@ -264,17 +304,28 @@ class ThreadPoolExecutor(Executor):
                         need_vals = [np.copy(coord.x[s]) for s in dp.needs]
                     else:
                         x_snap = coord.x.copy()
+                if tel is not None:
+                    sec = tel.section(
+                        "block_eval", lane, task=tid,
+                        path="host" if dp is None else "plane",
+                        refresh=blk_vals is not None).open()
                 if dp is not None:
                     if blk_vals is not None:
                         dp.refresh(blk_vals)
                     vals, dev_norm = dp.step(*need_vals)
                 else:
                     vals = worker_eval(problem, cfg, x_snap, idx)
+                if tel is not None:
+                    sec.close()
                 if cfg.async_overhead > 0.0:
                     time.sleep(cfg.async_overhead)
                 delay = prof.sample_delay(rng)
                 if delay > 0.0:
+                    if tel is not None:
+                        sec = tel.section("delay", lane, task=tid).open()
                     time.sleep(delay)
+                    if tel is not None:
+                        sec.close()
                 if prof.sample_crash(rng):
                     # A crash is still an arrival: it counts toward the
                     # record cadence and the stop checks must run, or an
@@ -298,9 +349,18 @@ class ThreadPoolExecutor(Executor):
                         if tel is not None:
                             tel.instant("restart", f"w{w}")
                     continue
+                if tel is not None:
+                    t_wait = elapsed()
                 with lock, coord.busy():
+                    if tel is not None:
+                        tel.span("lock_wait", lane, t_wait, elapsed(),
+                                 task=tid, phase="arrival")
                     if stop.is_set():
+                        if tel is not None:
+                            tel.task_close(w, disp="stopped")
                         return
+                    if tel is not None:
+                        sec = tel.section("apply", lane, task=tid).open()
                     staleness = coord.wu - launch_wu
                     applied = coord.apply_return(
                         idx, vals, prof, staleness=staleness, worker=w
@@ -327,6 +387,8 @@ class ThreadPoolExecutor(Executor):
                                 and state["since_fire"] >= cfg.fire_every):
                             coord.maybe_fire_accel()
                             state["since_fire"] = 0
+                    if tel is not None:
+                        sec.close(applied=applied)
                     if coord.arrival_tick(elapsed()):
                         stop.set()
                     coord.maybe_checkpoint(elapsed(), _loop_state)

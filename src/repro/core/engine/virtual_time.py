@@ -72,6 +72,8 @@ class VirtualTimeExecutor(Executor):
         if cfg.mode not in ("sync", "async"):
             raise ValueError(f"unknown mode {cfg.mode!r}")
         coord = Coordinator(problem, cfg)
+        if coord.telemetry is not None:
+            coord.telemetry.set_time(0.0)  # the event loop's clock throughout
         compute = (
             cfg.compute_time if cfg.compute_time is not None
             else measure_compute(problem, coord.blocks)  # memoized partition
@@ -800,6 +802,8 @@ class VirtualTimeExecutor(Executor):
                     coord_free = t_eff + eval_cost
                     # the recording worker waits out the busy window too
                     t_eff = t = coord_free
+                    if tel is not None:
+                        tel.set_time(coord_free)  # the record's span sits here
                     res = coord.record(coord_free)
                     if not np.isfinite(res) or res > 1e60:
                         break
@@ -814,5 +818,7 @@ class VirtualTimeExecutor(Executor):
                     push(t_eff + prof.restart_after, "restart", (worker,))
                 continue  # permanent crash: worker never relaunches
             launch(worker, t_eff)
+        if tel is not None:
+            tel.set_time(t)
         coord.record(t)
         return coord.result(t, coord.wu, coord.converged())

@@ -3,10 +3,13 @@
 One recorder (:class:`TelemetryRecorder`), owned by the coordinator when
 ``RunConfig.telemetry`` is set, collects typed spans (worker task
 dispatch→arrival, accel fire begin→commit, offloaded evaluations,
-checkpoint writes, SDC screens, serve admission→finish, scenario events)
-and metric series (applied-staleness histogram, residual vs clock,
-coordinator busy fraction, pool lease/respawn counts, serve queue depth)
-from every backend and service layer.  Exporters (:mod:`.export`) render
+checkpoint writes, SDC screens, serve admission→finish, scenario events,
+and the thread executor's leaf sections -- record, lock wait, block
+evaluation, delay, apply, warm-up; all but the lock wait also annotated
+on the JAX profiler's clock) and metric series (applied-staleness
+histogram, residual vs clock, coordinator busy fraction, pool
+lease/respawn counts, serve queue depth) from every backend and service
+layer.  Exporters (:mod:`.export`) render
 a capture as a JSONL event stream, a Chrome trace-event JSON viewable in
 Perfetto (one timeline lane per worker incarnation), or Prometheus text
 exposition for the serve layer; ``python -m repro.launch.run_report``
@@ -20,6 +23,7 @@ virtual goldens stay byte-identical with telemetry off *or on*
 """
 
 from .recorder import (
+    ANNOTATION_PREFIX,
     METRICS,
     SCENARIO_SPAN_MAP,
     SPAN_KINDS,
@@ -38,6 +42,7 @@ from .export import (
 )
 
 __all__ = [
+    "ANNOTATION_PREFIX",
     "METRICS",
     "SCENARIO_SPAN_MAP",
     "SPAN_KINDS",

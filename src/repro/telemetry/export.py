@@ -66,8 +66,13 @@ def to_chrome_trace(capture: TelemetryCapture) -> dict:
 
     One pid (the run), one tid per lane, ``ts`` sorted non-decreasing
     (Perfetto does not require it; :func:`validate_chrome_trace` does, so
-    exports are canonical).
+    exports are canonical).  A capture with spans before its clock's 0
+    (the thread executor's warm-up) starts its timeline at the earliest
+    of them; ``otherData["t_origin_s"]`` gives that time on the capture's
+    clock.
     """
+    origin = min([0.0] + [ev.get("t0", ev.get("t", 0.0))
+                          for ev in capture.events])
     lanes = trace_lanes(capture)
     tid = {lane: i for i, lane in enumerate(lanes)}
     events: List[dict] = []
@@ -85,22 +90,24 @@ def to_chrome_trace(capture: TelemetryCapture) -> dict:
         if "t0" in ev:
             body.append({"ph": "X", "pid": 1, "tid": tid.get(lane, 0),
                          "name": ev["k"], "cat": ev["k"],
-                         "ts": ev["t0"] * _US,
+                         "ts": (ev["t0"] - origin) * _US,
                          "dur": max(0.0, (ev["t1"] - ev["t0"]) * _US),
                          "args": args})
         else:
             body.append({"ph": "i", "pid": 1, "tid": tid.get(lane, 0),
                          "name": ev["k"], "cat": ev["k"], "s": "t",
-                         "ts": ev.get("t", 0.0) * _US, "args": args})
+                         "ts": (ev.get("t", 0.0) - origin) * _US,
+                         "args": args})
     for metric, points in capture.series.items():
         if metric == "staleness":
             continue  # a histogram, not a time series
         for t, v in points:
             body.append({"ph": "C", "pid": 1, "tid": 0, "name": metric,
-                         "ts": t * _US, "args": {metric: v}})
+                         "ts": (t - origin) * _US, "args": {metric: v}})
     body.sort(key=lambda e: e["ts"])
     meta = dict(capture.meta)
     meta["staleness_hist"] = capture.series.get("staleness", [])
+    meta["t_origin_s"] = origin
     return {"traceEvents": events + body, "displayTimeUnit": "ms",
             "otherData": meta}
 

@@ -19,6 +19,14 @@ Design constraints, in order:
    ones) installed via :meth:`TelemetryRecorder.install_clock` /
    :meth:`set_time`; host-side (perf_counter) durations ride along in
    span args where the two differ (inline fires on virtual time).
+   Sections timed before a real backend installs its clock (the thread
+   executor's warm-up) are re-based onto it at install, so they end at
+   or before 0.
+4. **Program spans on the profiler's clock.**  :meth:`section` times a
+   leaf section of the program and, where JAX is loaded, opens a
+   ``jax.profiler.TraceAnnotation`` named ``solver.<kind>`` over the same
+   interval on the same thread, so a device trace can put the chip's idle
+   time down to what the host was doing.
 
 Span taxonomy (``SPAN_KINDS``) and metric registry (``METRICS``) are the
 single source of truth: ``tools/docs_check.py`` asserts the README
@@ -30,14 +38,16 @@ an event kind can never be silently uninstrumented.
 
 from __future__ import annotations
 
+import itertools
+import sys
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
 
 __all__ = [
+    "ANNOTATION_PREFIX",
     "METRICS",
     "SCENARIO_SPAN_MAP",
     "SPAN_KINDS",
@@ -55,14 +65,25 @@ TELEMETRY_VERSION = 1
 #: trace-event kind and scenario-event kind maps into this taxonomy.
 SPAN_KINDS: Dict[str, str] = {
     "task": "one worker task: dispatch -> compute -> arrival, with "
-            "disposition (applied/filtered/crash/preempt_discard) and "
-            "applied staleness",
+            "disposition (applied/filtered/crash/preempt_discard/stopped), "
+            "applied staleness and (thread backend) the task id its "
+            "sections carry",
     "compute": "worker-side kernel evaluation only (process/ray workers "
                "measure it locally and ship batches over the result "
                "channel; anchored at the parent's receive clock)",
     "fire": "accel begin -> feed -> commit window, with the commit "
             "verdict (accept/fallback/discard/partial) and pin mode",
-    "record": "residual record: evaluation -> history append",
+    "record": "residual record: evaluation -> history append "
+              "(offloaded: record_begin -> commit)",
+    "lock_wait": "a thread worker waiting for the coordinator lock, at "
+                 "dispatch or arrival (never annotated: it would hide the "
+                 "holder)",
+    "block_eval": "a thread worker's evaluation of its block: device-plane "
+                  "refresh + step, or the host block update",
+    "delay": "a worker's injected fault-profile delay (the straggler)",
+    "apply": "a thread worker's arrival under the coordinator lock: "
+             "apply_return and its bookkeeping, up to the arrival tick",
+    "warm": "the thread executor's warm-up of the run's compiled shapes",
     "eval": "one offloaded evaluation item (full-map or residual norm) "
             "served by a worker/eval thread",
     "checkpoint": "checkpoint capture + atomic write",
@@ -196,6 +217,62 @@ class TelemetryCapture:
             return cls.from_dict(json.load(f))
 
 
+#: Prefix of every profiler annotation a section opens.  ``solver.*``
+#: sorts after the JAX host events a program section encloses
+#: (``shard_args``, ``np.asarray(jax.Array)``, ``PjitFunction(...)``), so
+#: a trace reader that labels an idle gap by the host event overlapping it
+#: most, breaking ties by the greater name, names the program section.
+ANNOTATION_PREFIX = "solver."
+
+
+def _annotation(kind: str):
+    """A profiler annotation for ``kind``, or None without JAX.
+
+    JAX is never imported here: a run that has not loaded it has no
+    profiler session to annotate, and ``import repro.telemetry`` stays
+    light."""
+    if "jax" not in sys.modules:
+        return None
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(ANNOTATION_PREFIX + kind)
+
+
+class Section:
+    """One timed program section (see :meth:`TelemetryRecorder.section`).
+
+    A context manager; ``open()`` / ``close(**args)`` do the same where a
+    ``with`` block would have to repeat a long untraced path.  ``args``
+    are the span's args: the body may add results to them."""
+
+    __slots__ = ("rec", "kind", "lane", "args", "t0", "_ann")
+
+    def __init__(self, rec: "TelemetryRecorder", kind: str, lane: str,
+                 args: dict):
+        self.rec, self.kind, self.lane, self.args = rec, kind, lane, args
+        self._ann = _annotation(kind)
+        self.t0 = 0.0
+
+    def open(self) -> "Section":
+        if self._ann is not None:
+            self._ann.__enter__()
+        self.t0 = self.rec.now()
+        return self
+
+    def close(self, **args) -> None:
+        t1 = self.rec.now()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        self.args.update(args)
+        self.rec._section_done(self, t1)
+
+    def __enter__(self) -> "Section":
+        return self.open()
+
+    def __exit__(self, *exc) -> None:
+        self.close()
+
+
 def _percentile(sorted_vals, q: float) -> float:
     """Nearest-rank percentile of a sorted sequence (0 if empty)."""
     if not sorted_vals:
@@ -233,21 +310,37 @@ class TelemetryRecorder:
         # count is what lets inline fires report ``fire_window_arrivals``
         # (arrivals whose flight overlapped the fire — see satellite fix
         # in ``Coordinator.maybe_fire_accel``).
-        self._open: Dict[Tuple[int, int], Tuple[float, Optional[int]]] = {}
+        self._open: Dict[Tuple[int, int],
+                         Tuple[float, Optional[int], Optional[int]]] = {}
         # Clocks: the backend installs its own (virtual or elapsed-wall);
         # until then ``now()`` is host seconds since construction.
         self._t0_host = time.perf_counter()
         self._now: Optional[Callable[[], float]] = None
         self._vt = 0.0
+        # Sections timed on the host clock before a real backend installs
+        # its own, re-based onto it at install.
+        self._provisional: List[dict] = []
         # Host-side coordinator busy accounting (virtual inline runs have
         # no backend-metered busy_s; this is the recorder-side fallback).
         self.host_busy_s = 0.0
         self._busy_tick = 0
+        self._task_ids = itertools.count()
 
     # ---- clocks ------------------------------------------------------- #
     def install_clock(self, fn: Callable[[], float]) -> None:
-        """Real backends: route ``now()`` to the loop's ``elapsed()``."""
-        self._now = fn
+        """Real backends: route ``now()`` to the loop's ``elapsed()``.
+
+        Sections timed before, on the host clock since construction, move
+        onto ``fn``'s clock (both read ``perf_counter``), so one capture
+        never mixes clock origins."""
+        with self._lock:
+            if self._now is None and self._provisional:
+                shift = fn() - (time.perf_counter() - self._t0_host)
+                for ev in self._provisional:
+                    ev["t0"] += shift
+                    ev["t1"] += shift
+            self._provisional.clear()
+            self._now = fn
 
     def set_time(self, t: float) -> None:
         """Virtual backend: pin ``now()`` to the event loop's clock."""
@@ -271,24 +364,17 @@ class TelemetryRecorder:
         el = self.host_elapsed()
         return min(1.0, self.host_busy_s / el) if el > 0 else 0.0
 
-    @contextmanager
-    def host_busy(self):
-        """Charge a host-clock coordinator section (inline fires/records
-        on the virtual backend, where virtual time charges nothing)."""
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.host_busy_s += time.perf_counter() - t0
-
     # ---- raw emits ---------------------------------------------------- #
     def _emit(self, ev: dict) -> None:
         with self._lock:
-            k = ev["k"]
-            self.span_counts[k] = self.span_counts.get(k, 0) + 1
-            if len(self.events) == self.events.maxlen:
-                self.dropped += 1
-            self.events.append(ev)
+            self._append(ev)
+
+    def _append(self, ev: dict) -> None:
+        k = ev["k"]
+        self.span_counts[k] = self.span_counts.get(k, 0) + 1
+        if len(self.events) == self.events.maxlen:
+            self.dropped += 1
+        self.events.append(ev)
 
     def span(self, kind: str, lane: str, t0: float, t1: float,
              **args) -> None:
@@ -306,6 +392,33 @@ class TelemetryRecorder:
             ev.update(args)
         self._emit(ev)
 
+    def section(self, kind: str, lane: str, **args) -> Section:
+        """Time one leaf section of the program as a ``kind`` span.
+
+        The span runs from entry to exit on the recorder's clock, and a
+        ``jax.profiler.TraceAnnotation`` named ``solver.<kind>`` (see
+        :data:`ANNOTATION_PREFIX`) covers the same interval on the calling
+        thread, so the section shows on a device trace's clock.  Use it
+        for leaf sections only: an enclosing annotation would overlap, and
+        so claim, every idle gap inside it.  A wait for a lock is a plain
+        :meth:`span`: its annotation would claim the holder's time.
+        """
+        return Section(self, kind, lane, args)
+
+    def _section_done(self, sec: Section, t1: float) -> None:
+        ev = {"k": sec.kind, "lane": sec.lane, "t0": sec.t0,
+              "t1": max(sec.t0, t1)}
+        ev.update(sec.args)
+        with self._lock:
+            self._append(ev)
+            if self._now is None:
+                self._provisional.append(ev)
+
+    def task_id(self) -> int:
+        """A fresh id for one worker task: its ``task`` span and every
+        section of it carry it as ``task``."""
+        return next(self._task_ids)
+
     def series_point(self, metric: str, t: float, value: float) -> None:
         with self._lock:
             s = self.series.get(metric)
@@ -322,10 +435,11 @@ class TelemetryRecorder:
             self.staleness_n += 1
 
     def task_open(self, worker: int, t: Optional[float] = None,
-                  gen: int = 0, block: Optional[int] = None) -> None:
+                  gen: int = 0, block: Optional[int] = None,
+                  task: Optional[int] = None) -> None:
         t = self.now() if t is None else float(t)
         with self._lock:
-            self._open[(int(worker), int(gen))] = (t, block)
+            self._open[(int(worker), int(gen))] = (t, block, task)
 
     def task_close(self, worker: int, t: Optional[float] = None,
                    disp: str = "applied", staleness: int = 0,
@@ -335,12 +449,14 @@ class TelemetryRecorder:
             entry = self._open.pop((int(worker), int(gen)), None)
         if entry is None:
             return  # truncated (e.g. a restore mid-flight): nothing to span
-        t0, block = entry
+        t0, block, task = entry
         ev = {"k": "task", "lane": worker_lane(worker, gen),
               "t0": float(t0), "t1": float(max(t0, t)), "disp": disp,
               "s": int(staleness)}
         if block is not None:
             ev["b"] = int(block)
+        if task is not None:
+            ev["task"] = int(task)
         self._emit(ev)
 
     @property

@@ -25,6 +25,7 @@ Covers the acceptance contract of the observability PR:
 
 import json
 import os
+import time
 
 import numpy as np
 import pytest
@@ -45,6 +46,7 @@ from repro.core.engine.coordinator import Coordinator
 from repro.launch.run_report import main as run_report_main
 from repro.problems import JacobiProblem
 from repro.telemetry import (
+    ANNOTATION_PREFIX,
     METRICS,
     SCENARIO_SPAN_MAP,
     SPAN_KINDS,
@@ -451,3 +453,208 @@ class TestServeTelemetry:
     def test_parse_rejects_malformed(self):
         with pytest.raises(ValueError):
             parse_prometheus("this is { not exposition\n")
+
+
+# --------------------------------------------------------------------- #
+# Thread executor: leaf sections on the run's clock and the profiler's
+# --------------------------------------------------------------------- #
+_STRAGGLE_S = 0.02  # worker 0's delay per update
+_SECTION_KINDS = ("lock_wait", "block_eval", "delay", "apply")
+
+
+def _thread_cfg(mode: str, **kw) -> RunConfig:
+    return RunConfig(executor="thread", mode=mode, n_workers=4, seed=5,
+                     tol=1e-300, max_updates=24, device_plane="jnp",
+                     faults={0: FaultProfile(delay_mean=_STRAGGLE_S)},
+                     telemetry=True, **kw)
+
+
+@pytest.fixture(scope="module")
+def thread_runs():
+    """One traced async solve on the device plane and one sync solve."""
+    prob = JacobiProblem(grid=32, sweeps=4, seed=0)
+    return {mode: run_fixed_point(prob, _thread_cfg(mode))
+            for mode in ("async", "sync")}
+
+
+def _spans(res, kind):
+    return [e for e in res.telemetry.events if e["k"] == kind]
+
+
+class TestThreadSections:
+    @pytest.mark.parametrize("mode", ["async", "sync"])
+    def test_every_record_is_timed(self, thread_runs, mode):
+        res = thread_runs[mode]
+        recs = _spans(res, "record")
+        assert len(recs) == len(res.history)
+        assert all(e["t1"] > e["t0"] for e in recs)
+        assert [e["res"] for e in recs] == [h[2] for h in res.history]
+
+    def test_async_block_evals_take_the_device_plane(self, thread_runs):
+        res = thread_runs["async"]
+        evals = _spans(res, "block_eval")
+        assert {e["path"] for e in evals} == {"plane"}
+        # A task evaluated when the run stopped is never applied.
+        assert (res.device_dispatches <= len(evals)
+                <= res.device_dispatches + 4)
+        assert res.device_dispatches == res.worker_updates
+        assert any(e["refresh"] for e in evals)
+
+    def test_sync_block_evals_take_the_host_path(self, thread_runs):
+        res = thread_runs["sync"]
+        evals = _spans(res, "block_eval")
+        assert len(evals) == res.worker_updates
+        assert {e["path"] for e in evals} == {"host"}
+        assert not _spans(res, "lock_wait")  # the sync loop has no lock
+
+    @pytest.mark.parametrize("mode", ["async", "sync"])
+    def test_straggler_delay_spans(self, thread_runs, mode):
+        res = thread_runs[mode]
+        delays = _spans(res, "delay")
+        assert delays and {e["lane"] for e in delays} == {"w0"}
+        assert all(e["t1"] - e["t0"] >= _STRAGGLE_S for e in delays)
+
+    @pytest.mark.parametrize("mode", ["async", "sync"])
+    def test_sections_carry_their_task_id(self, thread_runs, mode):
+        res = thread_runs[mode]
+        tasks = {e["task"]: e for e in _spans(res, "task")}
+        assert len(tasks) == len(_spans(res, "task"))
+        sections = [e for e in res.telemetry.events
+                    if e["k"] in _SECTION_KINDS]
+        assert {e["k"] for e in sections} >= {"block_eval", "delay"}
+        for e in sections:
+            assert e["task"] in tasks, e
+            assert e["lane"] == tasks[e["task"]]["lane"]
+        if mode == "async":
+            phases = {e["phase"] for e in _spans(res, "lock_wait")}
+            assert phases == {"dispatch", "arrival"}
+            applies = _spans(res, "apply")
+            assert sum(e["applied"] for e in applies) == res.worker_updates
+
+    @pytest.mark.parametrize("mode", ["async", "sync"])
+    def test_warm_up_ends_before_the_clock_starts(self, thread_runs, mode):
+        warms = sorted(_spans(thread_runs[mode], "warm"),
+                       key=lambda e: e["t0"])
+        assert warms and warms[0]["lane"] == "coord"
+        assert warms[0]["t0"] < warms[0]["t1"] <= 0.0
+        # async: the device plan's warm-up runs once the clock has started
+        assert len(warms) == (2 if mode == "async" else 1)
+
+    @pytest.mark.parametrize("mode", ["async", "sync"])
+    def test_spans_lie_on_one_clock(self, thread_runs, mode):
+        res = thread_runs[mode]
+        spans = [e for e in res.telemetry.events if "t0" in e]
+        # async: the final record follows the stop at wall_time
+        end = max([res.wall_time] + [e["t1"] for e in _spans(res, "record")])
+        for e in spans:
+            if e["k"] != "warm":
+                assert 0.0 <= e["t0"] <= e["t1"] <= end, e
+        # Each record starts where the history's clock read its time.
+        for e, (t, _, _) in zip(_spans(res, "record"), res.history):
+            assert 0.0 <= e["t0"] - t < 0.05
+
+    def test_span_kinds_are_registered(self, thread_runs):
+        for res in thread_runs.values():
+            kinds = {e["k"] for e in res.telemetry.events}
+            assert kinds <= set(SPAN_KINDS)
+
+    def test_sections_are_profiler_annotations(self, tmp_path):
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        prob = JacobiProblem(grid=32, sweeps=4, seed=0)
+        run_fixed_point(prob, _thread_cfg("async"))  # compile outside
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with jax.profiler.TraceAnnotation("window"):
+                run_fixed_point(prob, _thread_cfg("async"))
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                            recursive=True)
+        names = {}
+        for plane in ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                for ev in line.events:
+                    names.setdefault(ev.name, set()).add(
+                        (plane.name, line.name))
+        (window_line,) = names["window"]
+        want = {ANNOTATION_PREFIX + k
+                for k in ("record", "block_eval", "delay", "apply", "warm")}
+        assert ANNOTATION_PREFIX == "solver."
+        for name in want:
+            assert window_line in names.get(name, ()), name
+        assert ANNOTATION_PREFIX + "lock_wait" not in names
+        assert not any(n.startswith(ANNOTATION_PREFIX + "task") for n in names)
+
+    def test_export_starts_at_the_warm_up(self, thread_runs):
+        cap = thread_runs["async"].telemetry
+        doc = to_chrome_trace(cap)
+        assert validate_chrome_trace(doc) == []
+        origin = min(e["t0"] for e in cap.events if "t0" in e)
+        assert origin < 0.0
+        assert doc["otherData"]["t_origin_s"] == origin
+
+
+class TestSectionUnit:
+    def test_section_times_its_body_and_takes_args(self):
+        rec = TelemetryRecorder()
+        clock = iter([1.0, 3.5])
+        rec.install_clock(lambda: next(clock))
+        with rec.section("block_eval", "w2", task=7) as sec:
+            sec.args["path"] = "host"
+        (ev,) = list(rec.events)
+        assert ev == {"k": "block_eval", "lane": "w2", "t0": 1.0, "t1": 3.5,
+                      "task": 7, "path": "host"}
+
+    def test_open_close_and_task_ids(self):
+        rec = TelemetryRecorder()
+        rec.install_clock(lambda: 2.0)
+        a, b = rec.task_id(), rec.task_id()
+        assert b == a + 1
+        rec.task_open(0, 1.0, task=a)
+        rec.section("apply", "w0", task=a).open().close(
+            applied=True)
+        rec.task_close(0, 2.5)
+        app, task = list(rec.events)
+        assert app["applied"] is True and app["task"] == task["task"] == a
+
+    def test_sections_before_the_clock_move_onto_it(self):
+        rec = TelemetryRecorder()
+        with rec.section("warm", "coord"):
+            pass
+        rec.instant("restart", "w0", 0.25)  # caller's clock: left alone
+        t0 = time.perf_counter()
+        rec.install_clock(lambda: time.perf_counter() - t0)
+        warm, restart = list(rec.events)
+        assert warm["t0"] <= warm["t1"] <= 0.0
+        assert warm["t1"] > -1.0
+        assert restart["t"] == 0.25
+        with rec.section("record", "coord"):
+            pass
+        assert list(rec.events)[-1]["t0"] >= 0.0
+
+    @pytest.mark.parametrize("loop", [
+        {},
+        {"mode": "sync"},
+        {"accel": AndersonConfig(m=4), "fire_every": 4},
+        # the evaluation-cost loops: coordinator placement serializes
+        # records and fires with arrivals; worker placement offloads them
+        {"accel": AndersonConfig(m=4), "fire_every": 4,
+         "accel_eval": "coordinator", "eval_time": 2e-3},
+        {"accel": AndersonConfig(m=4), "fire_every": 4,
+         "accel_eval": "worker", "eval_time": 2e-3},
+        {"capture_trace": True},
+    ])
+    def test_virtual_records_take_no_virtual_time(self, loop):
+        res = run_fixed_point(JacobiProblem(grid=12, sweeps=4, seed=0),
+                              _virt_cfg(telemetry=True, max_updates=40,
+                                        **loop))
+        recs = [e for e in res.telemetry.events if e["k"] == "record"]
+        assert len(recs) == len(res.history)
+        # An offloaded record spans its modeled evaluation; every other
+        # record sits at the history's time.
+        assert [(e["t0"], e["t0"] if e.get("offloaded") else e["t1"])
+                for e in recs] == [(t, t) for t, _, _ in res.history]
