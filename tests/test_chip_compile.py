@@ -69,6 +69,18 @@ def test_halo_sweeps_float64(one_chip):
     assert c.memory_analysis().output_size_in_bytes >= ROWS * G * 8
 
 
+def test_jacobi_residual_norm_float64(one_chip):
+    """The coordinator's record at the 2800 x 2800 grid, in float64: one
+    fused program whose only output is the 0-d norm."""
+    from repro.problems.jacobi import _residual_norm
+
+    g = 2800
+    c = _compile(lambda x, b: _residual_norm(x, b, g), one_chip,
+                 ((g * g,), jnp.float64), ((g * g,), jnp.float64))
+    # The scalar's buffer is padded to a tile (1 KiB), far below one row.
+    assert c.memory_analysis().output_size_in_bytes < g * 8
+
+
 def test_vi_block_step_float64(one_chip):
     """The jnp value-iteration block step at 2**18 states of S = 2**20."""
     from repro.problems.value_iteration import _vi_block_step

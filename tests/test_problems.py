@@ -82,6 +82,42 @@ class TestJacobi:
         p = JacobiProblem(grid=6)
         assert p.residual_norm(p.exact_solution()) < 1e-8
 
+    @pytest.mark.parametrize("x_kind", ["zeros", "random", "exact"])
+    @pytest.mark.parametrize("grid", [16, 17, 64])
+    def test_residual_norm_is_one_device_scalar(self, grid, x_kind):
+        """The fused on-device norm equals the host norm of the residual
+        vector and a plain numpy 5-point stencil, and only a 0-d float64
+        leaves the jitted function."""
+        import jax
+
+        from repro.problems.jacobi import _residual_norm
+
+        p = JacobiProblem(grid=grid, seed=3)
+        x = {"zeros": np.zeros(p.n),
+             "random": np.random.default_rng(grid).standard_normal(p.n),
+             "exact": p.exact_solution()}[x_kind]
+        got = p.residual_norm(x)
+        assert type(got) is float
+
+        xg = x.reshape(grid, grid)
+        ax = 4 * xg
+        ax[1:] -= xg[:-1]
+        ax[:-1] -= xg[1:]
+        ax[:, 1:] -= xg[:, :-1]
+        ax[:, :-1] -= xg[:, 1:]
+        plain = np.linalg.norm(p._b - ax.ravel())
+        # At the exact solution the residual is round-off of b - A x whose
+        # digits follow the stencil's summation order, so gaps are taken
+        # against the larger of the norm and ||b|| (the norm at x = 0).
+        scale = max(plain, np.linalg.norm(p._b))
+        assert abs(got - np.linalg.norm(p.residual(x))) <= 1e-13 * scale
+        assert abs(got - plain) <= 1e-13 * scale
+
+        out = jax.eval_shape(
+            lambda v: _residual_norm(v, p._b_j, grid),
+            jax.ShapeDtypeStruct((p.n,), np.float64))
+        assert out.shape == () and out.dtype == np.float64
+
 
 # --------------------------------------------------------------------- #
 # Value iteration
