@@ -2,7 +2,8 @@
 """Readings of the check over many seeds, in one process on the chip.
 
     python3 chipbench/calibrate.py --workload <cell> --seconds <s> \
-        --seeds 1,2,3 [--patch float32|unchanged|half|no_exchange|altered]
+        --seeds 1,2,3 [--patch float32|unchanged|half|no_exchange|altered|
+                               dropped_newest]
 
 Runs the cell once per seed, as ``run.py`` does, with the timed path
 broken by ``--patch`` where given (``faults.py``: ``float32`` is the
@@ -32,7 +33,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--seeds", required=True,
                     type=lambda s: [int(v) for v in s.split(",")])
-    ap.add_argument("--patch", choices=faults.KINDS)
+    ap.add_argument("--patch", choices=faults.KINDS + faults.COMBINE_KINDS)
     args = ap.parse_args(argv)
 
     import jax

@@ -70,9 +70,9 @@ def child(workload: str, seed: int, seconds: float) -> int:
             gcs["s"] += d
             gcs["max"] = max(gcs["max"], d)
 
-    def check(cell_, seed_, problem, rc, window_solves):
+    def check(cell_, seed_, problem, rc, window_solves, anderson=None):
         solves.extend(window_solves)
-        return checker(cell_, seed_, problem, rc, window_solves)
+        return checker(cell_, seed_, problem, rc, window_solves, anderson)
 
     harness.compiles, harness.check = compiles, check
     gc.callbacks.append(on_gc)

@@ -7,19 +7,32 @@ instance, sees it too:
 
 * ``float32`` (the control): the family's plain reference computed in
   float32 takes the program's place in every block update and residual:
-  the precision step below the configuration's float64;
+  the precision step below the configuration's float64; where the mix
+  accelerates, ``chipbench/anderson.py``'s reference in float32 also
+  takes the place of the program's Anderson state in every fire;
 * ``unchanged``: a block update returns its block as it was;
 * ``half``: a block update leaves the second half of its block as it was;
 * ``no_exchange``: a block update reads zeros for every value outside its
   block (the halo rows);
 * ``altered``: a block update scales its largest value by 1 + 1e-6.
+
+A patch returns the factory of the Anderson state (``AndersonConfig`` ->
+state) that the window's coordinators build in the program's place
+(``harness.anderson_kept``), or None.  ``COMBINE_KINDS`` break that state
+alone, for mixes with ``accel``:
+
+* ``dropped_newest``: the program's state with each (x, g, f) pushed one
+  push late, so its solve and combine leave out the newest column.
 """
 
 from __future__ import annotations
 
+import dataclasses
+
 import numpy as np
 
 KINDS = ("float32", "unchanged", "half", "no_exchange", "altered")
+COMBINE_KINDS = ("dropped_newest",)
 
 
 def _break(kind: str, new: np.ndarray, old: np.ndarray) -> np.ndarray:
@@ -73,14 +86,42 @@ class _BrokenPlan:
         return self.block.copy(), norm
 
 
-def patch(kind: str, cell, seed: int):
-    """A function that breaks a problem of ``cell`` in place."""
-    if kind not in KINDS:
-        raise ValueError(f"unknown fault {kind!r}; known: {KINDS}")
+class _DroppedNewest:
+    """The program's Anderson state, each triple pushed one push late
+    (held as a copy, since the coordinator's iterate changes after)."""
 
-    def apply(problem) -> None:
+    def __init__(self, state):
+        self.state, self.held = state, None
+
+    def push(self, x, g, f) -> None:
+        if self.held is not None:
+            self.state.push(*self.held)
+        self.held = tuple(np.array(v, np.float64) for v in (x, g, f))
+
+    def __getattr__(self, name):
+        return getattr(self.state, name)
+
+
+def patch(kind: str, cell, seed: int):
+    """A function that breaks a problem of ``cell`` in place and returns
+    the factory of the window's Anderson state, or None."""
+    if kind not in KINDS + COMBINE_KINDS:
+        raise ValueError(f"unknown fault {kind!r}; known: "
+                         f"{KINDS + COMBINE_KINDS}")
+    accel = "accel" in cell.mix
+    if kind in COMBINE_KINDS and not accel:
+        raise ValueError(f"{kind!r} needs a mix with accel; {cell.name} "
+                         "has none")
+
+    def apply(problem):
+        if kind == "dropped_newest":
+            from repro.core import AndersonState
+
+            return lambda config: _DroppedNewest(AndersonState(config))
         plan_of, update = problem.device_block_plan, problem.block_update
         if kind == "float32":
+            from chipbench import anderson
+
             ref = cell.family.Reference(cell.config, seed, np.float32)
             problem.block_update = lambda x, idx: ref.block_step(
                 x, idx).astype(np.float64)
@@ -88,7 +129,8 @@ def patch(kind: str, cell, seed: int):
             problem.device_block_plan = lambda idx, mode: (
                 None if plan_of(idx, mode) is None
                 else _RefPlan(ref, idx, problem.n))
-            return
+            return (lambda config: anderson.State(
+                dataclasses.asdict(config), np.float32)) if accel else None
 
         def block_update(x, idx):
             if kind == "no_exchange":
@@ -103,5 +145,6 @@ def patch(kind: str, cell, seed: int):
 
         problem.block_update = block_update
         problem.device_block_plan = device_block_plan
+        return None
 
     return apply

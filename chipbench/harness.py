@@ -5,9 +5,13 @@ file of its own that this module finds by name:
 
 * ``configs/<config>.json`` -- sizes, precision, fleet, faults, the limits
   of the check; its ``family`` names ``problems/<family>.py``, which builds
-  the program's problem and holds the plain reference and kernel costs;
-* ``mixes/<traffic>.json`` -- the run's mode, device plane and straggler
-  delays;
+  the program's problem and holds the plain reference, the kernel costs
+  and ``TINY``, the configuration keys that shrink it for the CPU tests;
+* ``mixes/<traffic>.json`` -- the keys of ``MIX_KEYS``: the run's
+  ``mode``, ``device_plane`` and straggler ``delay_s``; optionally
+  ``accel`` (``AndersonConfig`` fields), ``limits`` (the limits of the
+  checks the mix adds, beside the configuration's) and ``why`` (free
+  text, read by nothing);
 * ``metrics/<metric>.py`` -- ``read(window)``, the number or None.
 
 The window is a closed loop of one caller: solves from x0 = 0 back to back
@@ -17,6 +21,8 @@ left as its ``max_wall``, until the window's seconds are used up.
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import importlib.util
 import json
 import math
@@ -52,8 +58,19 @@ def _json(base: Path, kind: str, name: str) -> dict:
     return json.loads(path.read_text())
 
 
+#: the keys a mix file may hold (see the module docstring)
+MIX_KEYS = ("mode", "device_plane", "delay_s", "accel", "limits", "why")
+
+
 @dataclass
 class Cell:
+    """One cell: its configuration, its mix, the family module the
+    configuration names, and the metrics it reports.
+
+    The mix holds ``mode``, ``device_plane`` and ``delay_s`` and may hold
+    ``accel`` (see ``run_config``), ``limits`` (see ``limits``) and
+    ``why``: ``MIX_KEYS``."""
+
     name: str
     chips: int
     config: dict
@@ -63,6 +80,18 @@ class Cell:
     per_layer: List[str]
     units: Dict[str, str]
     base: Path = HERE
+
+    @property
+    def limits(self) -> Dict[str, float]:
+        """The check's limits: the configuration's and the mix's.  A mix
+        limits only the checks it adds: naming a limit the configuration
+        sets raises ``KeyError``, so that no mix loosens it."""
+        own, mix = self.config["limits"], self.mix.get("limits", {})
+        both = sorted(set(own) & set(mix))
+        if both:
+            raise KeyError(f"mix of {self.name!r} sets the limits {both} "
+                           "that its configuration sets")
+        return {**own, **mix}
 
 
 def _reports(metric: dict, cell: str) -> bool:
@@ -100,16 +129,27 @@ def load_cell(name: str, bench: Optional[dict] = None, base: Path = HERE,
 
 
 def run_config(cell: Cell, seed: int, **kw):
-    """The ``RunConfig`` the mix gives this cell's configuration."""
-    from repro.core import FaultProfile, RunConfig
+    """The ``RunConfig`` the mix gives this cell's configuration.
+
+    ``mode``, ``device_plane`` and ``delay_s`` (worker -> seconds added
+    per update) are required; ``accel`` becomes ``RunConfig.accel =
+    AndersonConfig(**accel)``; ``limits`` is the check's and ``why`` is
+    read by nothing.  A key outside ``MIX_KEYS`` raises ``KeyError``,
+    so that a misspelt key cannot run a plain window."""
+    from repro.core import AndersonConfig, FaultProfile, RunConfig
 
     mix = cell.mix
+    unknown = sorted(set(mix) - set(MIX_KEYS))
+    if unknown:
+        raise KeyError(f"mix of {cell.name!r} has unknown keys {unknown}; "
+                       f"known: {list(MIX_KEYS)}")
+    accel = AndersonConfig(**mix["accel"]) if "accel" in mix else None
     return RunConfig(
         executor="thread", mode=mix["mode"],
         n_workers=cell.config["n_workers"], tol=cell.config["tol"],
         device_plane=mix["device_plane"], seed=seed,
         faults={int(w): FaultProfile(delay_mean=d)
-                for w, d in mix["delay_s"].items()}, **kw)
+                for w, d in mix["delay_s"].items()}, accel=accel, **kw)
 
 
 # --------------------------------------------------------------------- #
@@ -206,23 +246,113 @@ def program_block_step(problem, rc, indices: np.ndarray,
     return np.asarray(vals)
 
 
-def check(cell: Cell, seed: int, problem, rc, solves: list) -> dict:
+#: points of the iterate at which the window's Anderson steps are kept
+ANDERSON_SAMPLE = 2 ** 16
+
+
+def anderson_sample(n: int, seed: int) -> np.ndarray:
+    """The sorted points, drawn from the seed, at which ``Recorded`` keeps
+    the window's Anderson steps: every point where ``n`` is small."""
+    if n <= ANDERSON_SAMPLE:
+        return np.arange(n)
+    rng = np.random.default_rng([seed, 0xA5])
+    return np.unique(rng.integers(0, n, ANDERSON_SAMPLE))
+
+
+class Recorded:
+    """The Anderson state that the coordinator of one solve holds, kept
+    for the check.
+
+    ``state`` is the program's ``AndersonState``, or what a fault puts in
+    its place.  At the points ``at`` this keeps each (x, g, f) that the
+    coordinator pushed (``pushed``, the last m + 1, oldest first) and the
+    step and coefficients of the last ``propose()`` (``step``, None where
+    it gave no step).  A sample and no copy, so that a fire in the window
+    pays a gather of ``at`` and no O(n) copy.  Everything else is the
+    state's own."""
+
+    def __init__(self, state, at: np.ndarray, m: int):
+        self.state, self.at = state, at
+        self.pushed = collections.deque(maxlen=m + 1)
+        self.step = None
+
+    def push(self, x, g, f) -> None:
+        self.pushed.append(tuple(np.array(np.asarray(v)[self.at], np.float64)
+                                 for v in (x, g, f)))
+        self.state.push(x, g, f)
+
+    def propose(self):
+        out = self.state.propose()
+        alpha = self.state.last_alpha
+        self.step = None if out is None or alpha is None else (
+            np.array(np.asarray(out)[self.at], np.float64),
+            np.array(alpha, np.float64))
+        return out
+
+    def __getattr__(self, name):
+        return getattr(self.state, name)
+
+
+def anderson_gap(accel: dict, rec: Optional[Recorded]) -> float:
+    """The last Anderson step of the solve whose state ``rec`` kept,
+    against the plain reference (``chipbench/anderson.py``).
+
+    The larger of (a) the window: each row of the state's own window
+    (``snapshot()``'s X, G, F) against what the coordinator pushed, at
+    the sample, over the pushed rows' largest value; and (b)
+    ``chipbench/anderson.py``'s ``gap`` of the step with the state's own
+    coefficients: its combine of the pushed rows at the sample, its
+    least-squares solve on the state's whole F.  No step, or a window
+    that is not the pushed one's length, reads infinite."""
+    from chipbench import anderson as ref
+
+    if rec is None or rec.step is None or not rec.pushed:
+        return math.inf
+    snap = rec.state.snapshot()
+    if snap.get("F") is None or len(snap["F"]) != len(rec.pushed):
+        return math.inf
+    pushed = [np.stack(rows) for rows in zip(*rec.pushed)]  # X, G, F
+    window = max(float(np.max(np.abs(np.asarray(snap[k])[:, rec.at] - p))
+                       / np.max(np.abs(p)))
+                 for k, p in zip("XGF", pushed))
+    F = np.asarray(snap["F"], np.float64)
+    x_acc, alpha = rec.step
+    if len(F) == 1:  # a window of one steps to its map value
+        alpha = np.ones(1)
+    if len(alpha) != len(F):
+        return math.inf
+    return max(window, ref.gap(pushed[0], pushed[1], F, x_acc, alpha, accel))
+
+
+def check(cell: Cell, seed: int, problem, rc, solves: list,
+          anderson: Optional[Recorded] = None) -> dict:
     """Each solve's answer against the family's float64 reference.
 
     ``residual_gap``: the program's first and last recorded residual
     against the reference's at x0 and at the final iterate, as a share of
     the reference's residual at x0.  ``block_step_gap``: one update of
     every block of the final iterate through the program's path against
-    the reference's, as a share of the largest reference value.  Returns
-    the worst reading of each over the solves, and the solves that failed.
+    the reference's, as a share of the largest reference value.  Where the
+    mix has ``accel``: ``anderson_gap``, read once, at the last fire of the
+    last solve, from ``anderson``, the state that solve's coordinator held
+    (see ``anderson_gap``); and ``acceptless_solves``, the solves in which
+    no Anderson step was accepted, limit 0.  Returns the worst reading of
+    each over the solves, the limits, and the solves that failed.
     """
     ref = cell.family.Reference(cell.config, seed)
-    limits = cell.config["limits"]
+    accel = cell.mix.get("accel")
+    limits = dict(cell.limits)
     blocks = problem.default_blocks(cell.config["n_workers"])
     r0 = ref.residual_norm(problem.initial())
-    worst = {k: 0.0 for k in limits}
+    worst = {"residual_gap": 0.0, "block_step_gap": 0.0}
+    if accel is not None:
+        limits["acceptless_solves"] = 0
+        worst.update(anderson_gap=0.0, acceptless_solves=0)
+    if set(worst) != set(limits):
+        raise KeyError(f"{cell.name}: the check reads {sorted(worst)}, the "
+                       f"limits name {sorted(limits)}")
     failed = 0
-    for res in solves:
+    for i, res in enumerate(solves):
         x = np.asarray(res.x)
         gap = {"residual_gap": max(
             abs(res.history[0][2] - r0),
@@ -232,11 +362,17 @@ def check(cell: Cell, seed: int, problem, rc, solves: list) -> dict:
         gap["block_step_gap"] = max(
             float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
             for got, want in steps)
-        failed += any(not gap[k] <= limits[k] for k in limits)
-        for k in limits:  # a NaN reading stays NaN
+        if accel is not None and i == len(solves) - 1:
+            gap["anderson_gap"] = anderson_gap(accel, anderson)
+        bad = any(not gap[k] <= limits[k] for k in gap)
+        if accel is not None and res.accel_accepts == 0:
+            worst["acceptless_solves"] += 1
+            bad = True
+        failed += bad
+        for k in gap:  # a NaN reading stays NaN
             if not gap[k] <= worst[k]:
                 worst[k] = gap[k]
-    return {"readings": worst, "failed": failed}
+    return {"readings": worst, "limits": limits, "failed": failed}
 
 
 # --------------------------------------------------------------------- #
@@ -271,40 +407,70 @@ def _profile(logdir: str):
     jax.profiler.start_trace(logdir, profiler_options=opts)
 
 
+@contextlib.contextmanager
+def anderson_kept(cell: Cell, seed: int, problem,
+                  state_of: Optional[Callable] = None):
+    """Inside, where the mix accelerates, the coordinator of each solve
+    builds its Anderson state through ``state_of(config)`` (the program's
+    ``AndersonState`` where None) and holds it wrapped in a ``Recorded``.
+    Yields a list whose one item is the newest solve's ``Recorded``."""
+    newest = [None]
+    if "accel" not in cell.mix:
+        yield newest
+        return
+    from repro.core.engine import coordinator
+
+    program = coordinator.AndersonState
+    make, at = state_of or program, anderson_sample(problem.n, seed)
+
+    def build(config):
+        newest[0] = Recorded(make(config), at, config.m)
+        return newest[0]
+
+    coordinator.AndersonState = build
+    try:
+        yield newest
+    finally:
+        coordinator.AndersonState = program
+
+
 def run(cell: Cell, seed: int, seconds: float, trace: bool,
         t_start: float, patch: Optional[Callable] = None) -> Outcome:
     """Set up, measure ``seconds``, check, and read the cell's metrics.
 
     ``t_start`` is the process's start on ``time.perf_counter``.
     ``patch(problem)``, where given, breaks the timed path in place (the
-    control and the planted faults of ``faults.py``)."""
+    control and the planted faults of ``faults.py``) and returns the
+    factory of the Anderson state that the window's coordinators build in
+    the program's place, or None."""
     import jax
     from repro.core import run_fixed_point
 
     fam = cell.family
     problem = fam.build(cell.config, seed)
-    if patch is not None:
-        patch(problem)
+    state_of = patch(problem) if patch is not None else None
     fam.prepare(problem)
-    # Warm-up: one short solve of the cell's own run shape compiles (or
-    # loads from the persistent cache) every program the window drives.
-    run_fixed_point(problem, run_config(
-        cell, seed, max_updates=cell.config["n_workers"],
-        telemetry=trace or None))
-
     logdir = tempfile.mkdtemp(prefix="chipbench-trace-") if trace else None
     try:
-        if trace:
-            _profile(logdir)
-        c0 = compiles()
-        t0 = time.perf_counter()
-        solves = []
-        with jax.profiler.TraceAnnotation("window"):
-            while (left := t0 + seconds - time.perf_counter()) > 0:
-                with jax.profiler.TraceAnnotation("solve"):
-                    solves.append(run_fixed_point(problem, run_config(
-                        cell, seed, max_wall=left, telemetry=trace or None)))
-        t1 = time.perf_counter()
+        with anderson_kept(cell, seed, problem, state_of) as newest:
+            # Warm-up: one short solve of the cell's own run shape compiles
+            # (or loads from the persistent cache) every program the window
+            # drives.
+            run_fixed_point(problem, run_config(
+                cell, seed, max_updates=cell.config["n_workers"],
+                telemetry=trace or None))
+            if trace:
+                _profile(logdir)
+            c0 = compiles()
+            t0 = time.perf_counter()
+            solves = []
+            with jax.profiler.TraceAnnotation("window"):
+                while (left := t0 + seconds - time.perf_counter()) > 0:
+                    with jax.profiler.TraceAnnotation("solve"):
+                        solves.append(run_fixed_point(problem, run_config(
+                            cell, seed, max_wall=left,
+                            telemetry=trace or None)))
+            t1 = time.perf_counter()
         n_compiles = compiles() - c0
         summary = None
         if trace:
@@ -323,8 +489,12 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
               "updates": win.updates,
               "device_dispatches": sum(r.device_dispatches for r in solves),
               "compiles": n_compiles}
-    verdict = check(cell, seed, problem, run_config(cell, seed), solves)
-    checks = {k: {"value": v, "limit": cell.config["limits"][k]}
+    if cell.mix.get("accel") is not None:
+        counts["accel_fires"] = sum(r.accel_fires for r in solves)
+        counts["accel_accepts"] = sum(r.accel_accepts for r in solves)
+    verdict = check(cell, seed, problem, run_config(cell, seed), solves,
+                    newest[0])
+    checks = {k: {"value": v, "limit": verdict["limits"][k]}
               for k, v in verdict["readings"].items()}
 
     metrics = {}

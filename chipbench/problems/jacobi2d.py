@@ -9,6 +9,9 @@ from __future__ import annotations
 
 import numpy as np
 
+#: configuration keys that shrink a cell of this family for the CPU tests
+TINY = {"grid": 32}
+
 
 def build(config: dict, seed: int):
     """The program's problem for this configuration and seed."""

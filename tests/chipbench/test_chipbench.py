@@ -3,11 +3,14 @@
 The metrics' arithmetic, the trace reduction, the kernels' operation and
 byte counts, finding cells by name, and each cell's set-up, window and
 check; the check's control and planted faults must come out not correct.
-The chip runs the same code at full size (``BENCHMARK.json``).
+Each cell runs at its family's ``TINY`` size (``problems/<family>.py``),
+so that a cell of a new family needs no edit here.  The chip runs the
+same code at full size (``BENCHMARK.json``).
 """
 
 from __future__ import annotations
 
+import dataclasses
 import json
 import math
 import os
@@ -18,35 +21,42 @@ import time
 from pathlib import Path
 from types import SimpleNamespace
 
+import numpy as np
 import pytest
 
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
-from chipbench import faults, harness, peaks  # noqa: E402
+from chipbench import anderson, faults, harness, peaks  # noqa: E402
 from chipbench import trace as tr  # noqa: E402
 
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 CELLS = [w["name"] for w in BENCH["workloads"]]
-TINY = {"jacobi2d": {"grid": 32}}
 JACOBI = "jacobi2d_g2800"
+ANDERSON = "async_anderson_straggler"
 DATA = Path(__file__).resolve().parent / "data"
 
 
-def tiny(name: str, bench: dict = BENCH) -> harness.Cell:
-    wl = next(w for w in bench["workloads"] if w["name"] == name)
-    family = harness._json(harness.HERE, "configs", wl["config"])["family"]
-    return harness.load_cell(name, bench, overrides=TINY[family])
+def tiny(name: str, bench: dict = BENCH,
+         base: Path = harness.HERE) -> harness.Cell:
+    """The cell ``name`` at its family's ``TINY`` size."""
+    family = harness.load_cell(name, bench, base=base).family
+    return harness.load_cell(name, bench, base=base, overrides=family.TINY)
 
 
-def with_cell(config: str, traffic: str) -> dict:
-    """``BENCHMARK.json`` with the cell ``<config>.<traffic>`` in it."""
+def with_cell(config: str, traffic: str, like: str = "") -> dict:
+    """``BENCHMARK.json`` with the cell ``<config>.<traffic>`` in it, which
+    reports the per-layer metrics of the cell ``like``, as the entries a
+    later change extends would have it."""
     bench = json.loads(json.dumps(BENCH))
     name = f"{config}.{traffic}"
     if name not in CELLS:
         bench["workloads"].append({"name": name, "config": config,
                                    "traffic": traffic, "chips": 1,
                                    "why": "test"})
+        for m in bench["per_layer"]:
+            if like in m["workloads"]:
+                m["workloads"].append(name)
     return bench
 
 
@@ -200,6 +210,7 @@ def test_every_name_has_its_file():
     moves = {m["name"]: m["moves"] for m in BENCH["per_layer"]}
     for name in CELLS:
         cell = harness.load_cell(name)
+        assert set(cell.family.TINY) <= set(cell.config)  # its CPU size
         assert {"point_updates_per_s", "setup_s"} <= set(cell.end_to_end)
         assert "idle_share" in cell.per_layer
         # a cell reports the end-to-end metric each of its layers moves
@@ -246,9 +257,7 @@ DEVICE_METRICS = {m["name"] for m in BENCH["per_layer"]
                   if m["source"] == "device_trace"}
 
 
-@pytest.mark.parametrize("name", CELLS)
-def test_cell_sets_up_measures_and_checks(name, device_plane_at_small_n):
-    cell = tiny(name)
+def sets_up_measures_and_checks(cell: harness.Cell) -> None:
     seed = 2 ** 33 + 5  # seeds may pass 32 bits
     out = harness.run(cell, seed, 0.6, False, t_start=time.perf_counter())
     line = out.line
@@ -262,6 +271,10 @@ def test_cell_sets_up_measures_and_checks(name, device_plane_at_small_n):
     assert values["point_updates_per_s"] > 0 and values["setup_s"] > 0
     assert math.isfinite(values.get("decades_per_s", 0.0))
     assert list(line)[-1] == "checks"
+    accel = "accel" in cell.mix
+    assert list(line["checks"]) == ["residual_gap", "block_step_gap"] + (
+        ["anderson_gap", "acceptless_solves"] if accel else [])
+    assert ("accel_fires" in out.window) == accel
     for c in line["checks"].values():
         assert 0 <= c["value"] <= c["limit"]
     assert line["device"]["platform"] == "cpu"
@@ -277,6 +290,11 @@ def test_cell_sets_up_measures_and_checks(name, device_plane_at_small_n):
     assert "busy_s" not in traced["device"] and "breakdown" not in traced
 
 
+@pytest.mark.parametrize("name", CELLS)
+def test_cell_sets_up_measures_and_checks(name, device_plane_at_small_n):
+    sets_up_measures_and_checks(tiny(name))
+
+
 def test_run_refuses_a_machine_without_a_tpu():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     out = subprocess.run(
@@ -290,11 +308,9 @@ def test_run_refuses_a_machine_without_a_tpu():
 # --------------------------------------------------------------------- #
 # the control and the planted faults fail the check
 # --------------------------------------------------------------------- #
-@pytest.mark.parametrize("kind", faults.KINDS)
-@pytest.mark.parametrize("name", CELLS)
-def test_control_and_faults_are_not_correct(name, kind,
-                                            device_plane_at_small_n):
-    cell = tiny(name)
+def is_not_correct(cell: harness.Cell, kind: str) -> dict:
+    """The run of ``cell`` with the fault ``kind``: not correct, with a
+    number past its limit.  Returns the readings."""
     out = harness.run(cell, 7, 0.3, False, t_start=time.perf_counter(),
                       patch=faults.patch(kind, cell, 7))
     line = out.line
@@ -302,9 +318,245 @@ def test_control_and_faults_are_not_correct(name, kind,
     readings = {k: c["value"] for k, c in line["checks"].items()}
     assert any(not v <= line["checks"][k]["limit"]
                for k, v in readings.items()), readings
-    if kind == "float32":  # the control fails both numbers
+    if kind == "float32":  # the control fails every gap
         assert all(v > line["checks"][k]["limit"] and math.isfinite(v)
-                   for k, v in readings.items())
+                   for k, v in readings.items() if k.endswith("_gap"))
+    return readings
+
+
+@pytest.mark.parametrize("kind", faults.KINDS)
+@pytest.mark.parametrize("name", CELLS)
+def test_control_and_faults_are_not_correct(name, kind,
+                                            device_plane_at_small_n):
+    is_not_correct(tiny(name), kind)
+
+
+# --------------------------------------------------------------------- #
+# a new family as files only
+# --------------------------------------------------------------------- #
+TWIN = "jacobi2d_twin"
+
+
+@pytest.fixture(scope="module")
+def twin_family(tmp_path_factory):
+    """A copy of ``chipbench/`` with one more family module and
+    configuration (``jacobi2d`` renamed), and ``BENCHMARK.json`` with its
+    cell: the files a later change would add, nothing edited.  Returns
+    (bench, base, cell name, the copied files' bytes)."""
+    base = tmp_path_factory.mktemp("bench") / "chipbench"
+    shutil.copytree(harness.HERE, base,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    before = {p: p.read_bytes() for p in base.rglob("*") if p.is_file()}
+    shutil.copy(base / "problems" / "jacobi2d.py",
+                base / "problems" / f"{TWIN}.py")
+    config = json.loads((base / "configs" / f"{JACOBI}.json").read_text())
+    (base / "configs" / f"{TWIN}_g2800.json").write_text(
+        json.dumps(dict(config, family=TWIN)))
+    bench = with_cell(f"{TWIN}_g2800", "async_straggler",
+                      like=f"{JACOBI}.async_straggler")
+    bench["configs"].append({"name": f"{TWIN}_g2800", "source": "test",
+                             "file": f"chipbench/configs/{TWIN}_g2800.json",
+                             "reduced": [], "why": "test"})
+    return bench, base, f"{TWIN}_g2800.async_straggler", before
+
+
+def test_a_family_module_and_config_add_a_cell(twin_family,
+                                               device_plane_at_small_n):
+    bench, base, name, before = twin_family
+    cell = tiny(name, bench, base)
+    assert cell.family.__file__ == str(base / "problems" / f"{TWIN}.py")
+    assert cell.config["family"] == TWIN
+    assert cell.config["grid"] == cell.family.TINY["grid"] == 32
+    assert all(p.read_bytes() == b for p, b in before.items())
+    sets_up_measures_and_checks(cell)
+
+
+@pytest.mark.parametrize("kind", faults.KINDS)
+def test_a_new_familys_control_and_faults_are_not_correct(
+        twin_family, kind, device_plane_at_small_n):
+    bench, base, name, _ = twin_family
+    is_not_correct(tiny(name, bench, base), kind)
+
+
+# --------------------------------------------------------------------- #
+# a mix that accelerates, and the check of the Anderson step
+# --------------------------------------------------------------------- #
+def anderson_cell(**mix) -> harness.Cell:
+    """The tiny Jacobi cell of the Anderson mix, its mix updated."""
+    cell = tiny(f"{JACOBI}.{ANDERSON}", with_cell(
+        JACOBI, ANDERSON, like=f"{JACOBI}.async_straggler"))
+    return dataclasses.replace(cell, mix=dict(cell.mix, **mix))
+
+
+def test_a_mix_sets_the_run_configs_acceleration():
+    from repro.core import AndersonConfig
+
+    rc = harness.run_config(anderson_cell(), 1)
+    assert rc.accel == AndersonConfig(m=5)
+    assert (rc.accel_mode, rc.fire_every) == ("coordinator", 1)
+    rc = harness.run_config(anderson_cell(accel={"m": 3, "beta": 0.5}), 1)
+    assert rc.accel == AndersonConfig(m=3, beta=0.5)
+    assert harness.run_config(tiny(f"{JACOBI}.async_straggler"), 1).accel \
+        is None
+
+
+@pytest.mark.parametrize("key", ("anderson_m", "accel_mode", "fire_every"))
+def test_an_unknown_mix_key_is_an_error(key):
+    with pytest.raises(KeyError, match=f"{key}.*known"):
+        harness.run_config(anderson_cell(**{key: 5}), 1)
+
+
+def test_every_mix_file_makes_a_run_config():
+    cell = first_cell(JACOBI)
+    for path in sorted((harness.HERE / "mixes").glob("*.json")):
+        mix = json.loads(path.read_text())
+        rc = harness.run_config(dataclasses.replace(cell, mix=mix), 1)
+        assert rc.mode == mix["mode"]
+        assert (rc.accel is None) == ("accel" not in mix)
+
+
+def test_a_limit_the_check_does_not_read_is_an_error():
+    cell = tiny(f"{JACOBI}.async_straggler")
+    cell = dataclasses.replace(cell, mix=dict(
+        cell.mix, limits={"anderson_gap": 1e-10}))
+    with pytest.raises(KeyError, match="limits name"):
+        harness.run(cell, 1, 0.1, False, t_start=time.perf_counter())
+
+
+@pytest.mark.parametrize("key", ("residual_gap", "block_step_gap"))
+def test_a_mix_cannot_set_a_configurations_limit(key):
+    cell = anderson_cell(limits={"anderson_gap": 1e-9, key: 1.0})
+    with pytest.raises(KeyError, match=f"{key}.*configuration sets"):
+        cell.limits
+    with pytest.raises(KeyError, match="configuration sets"):
+        harness.run(cell, 1, 0.1, False, t_start=time.perf_counter())
+
+
+@pytest.mark.parametrize("sample", (None, 256))
+def test_the_window_keeps_the_state_its_coordinator_held(
+        sample, device_plane_at_small_n, monkeypatch):
+    from repro.core import AndersonState, run_fixed_point
+    from repro.core.engine import coordinator
+
+    if sample is not None:  # as at full size, a sample of the points
+        monkeypatch.setattr(harness, "ANDERSON_SAMPLE", sample)
+    cell = anderson_cell()
+    problem = cell.family.build(cell.config, 4)
+    with harness.anderson_kept(cell, 4, problem) as newest:
+        res = run_fixed_point(problem, harness.run_config(
+            cell, 4, max_updates=12))
+        rec = newest[0]
+    assert coordinator.AndersonState is AndersonState  # put back
+    assert isinstance(rec, harness.Recorded)
+    assert isinstance(rec.state, AndersonState)
+    assert res.accel_fires == rec.n_fire > 0
+    assert res.accel_accepts == rec.n_accept
+    assert len(rec.pushed) == min(rec.n_fire, cell.mix["accel"]["m"] + 1)
+    assert rec.step is not None
+    assert len(rec.at) == problem.n if sample is None else \
+        0.8 * sample < len(rec.at) <= sample
+    assert harness.anderson_gap(cell.mix["accel"], rec) <= 1e-12
+    with harness.anderson_kept(cell, 4, problem) as newest:  # window of 2
+        res = run_fixed_point(problem, harness.run_config(
+            cell, 4, max_updates=2))
+    assert len(newest[0].pushed) == res.accel_fires == 2
+    assert harness.anderson_gap(cell.mix["accel"], newest[0]) <= 1e-12
+    with harness.anderson_kept(tiny(f"{JACOBI}.async_straggler"), 4,
+                               problem) as newest:
+        assert coordinator.AndersonState is AndersonState  # plain mix
+    assert newest == [None]
+
+
+def test_the_check_samples_a_large_iterate():
+    at = harness.anderson_sample(10 ** 7, 2 ** 33 + 1)
+    assert len(at) > 0.99 * harness.ANDERSON_SAMPLE
+    assert np.all(np.diff(at) > 0) and 0 <= at[0] and at[-1] < 10 ** 7
+    assert np.array_equal(at, harness.anderson_sample(10 ** 7, 2 ** 33 + 1))
+    assert not np.array_equal(at[:100], harness.anderson_sample(
+        10 ** 7, 2 ** 33 + 2)[:100])
+
+
+def test_an_accelerated_cell_fires_and_is_correct(device_plane_at_small_n):
+    cell = anderson_cell()
+    assert cell.limits["anderson_gap"] == cell.mix["limits"]["anderson_gap"]
+    sets_up_measures_and_checks(cell)
+    out = harness.run(cell, 11, 0.6, False, t_start=time.perf_counter())
+    w, checks = out.window, out.line["checks"]
+    assert out.line["correct"]
+    assert 0 < w["accel_accepts"] <= w["accel_fires"]
+    assert checks["acceptless_solves"] == {"value": 0, "limit": 0}
+    assert 0 <= checks["anderson_gap"]["value"] <= 1e-12  # rounding
+
+
+@pytest.mark.parametrize("kind", ("float32",) + faults.COMBINE_KINDS)
+def test_combine_control_and_fault_fail_anderson_gap(kind,
+                                                     device_plane_at_small_n):
+    cell = anderson_cell()
+    readings = is_not_correct(cell, kind)
+    assert readings["anderson_gap"] > cell.limits["anderson_gap"]
+
+
+def test_a_combine_fault_needs_an_accelerated_mix():
+    with pytest.raises(ValueError, match="needs a mix with accel"):
+        faults.patch("dropped_newest", first_cell(JACOBI), 1)
+
+
+class Rejected:
+    """The program's Anderson state with every step moved far off, so that
+    the safeguard rejects each one."""
+
+    def __init__(self, state):
+        self.state = state
+
+    def propose(self):
+        out = self.state.propose()
+        return None if out is None else out + 1e3
+
+    def __getattr__(self, name):
+        return getattr(self.state, name)
+
+
+def test_a_solve_without_an_accepted_step_fails(device_plane_at_small_n):
+    from repro.core import AndersonState
+
+    cell = anderson_cell()
+    out = harness.run(cell, 3, 0.3, False, t_start=time.perf_counter(),
+                      patch=lambda problem: (
+                          lambda config: Rejected(AndersonState(config))))
+    line = out.line
+    assert out.window["accel_fires"] > 0 == out.window["accel_accepts"]
+    assert not line["correct"] and line["failed"] == line["attempted"] >= 1
+    assert line["checks"]["acceptless_solves"]["value"] == line["attempted"]
+
+
+def test_anderson_reference_solves_the_constrained_least_squares():
+    from repro.core import AndersonConfig, AndersonState
+
+    rng = np.random.default_rng(5)
+    F = rng.standard_normal((6, 200))
+    reg = 1e-3
+    B = F @ F.T
+    lam = reg * np.trace(B) / 6
+    w = np.linalg.solve(B + lam * np.eye(6), np.ones(6))
+    want = w / w.sum()  # the constrained minimiser in closed form
+    got = anderson.alpha(F, reg)
+    assert got == pytest.approx(want, rel=1e-10)
+    assert anderson.objective(F, got, reg) == pytest.approx(
+        float(want @ (B + lam * np.eye(6)) @ want), rel=1e-10)
+    X = rng.standard_normal((6, 200))
+    state = anderson.State({"m": 5, "reg": reg, "beta": 0.5})
+    for x, f in zip(X, F):
+        state.push(x, x + f, f)
+    step = state.propose()
+    assert step == pytest.approx(want @ (X + 0.5 * F), rel=1e-10)
+    assert anderson.gap(X, X + F, F, step, state.last_alpha,
+                        {"reg": reg, "beta": 0.5}) < 1e-12
+    # the program's step on the same window reads as rounding
+    prog = AndersonState(AndersonConfig(m=5, reg=reg, beta=0.5))
+    for x, f in zip(X, F):
+        prog.push(x, x + f, f)
+    assert anderson.gap(X, X + F, F, prog.propose(), prog.last_alpha,
+                        {"reg": reg, "beta": 0.5}) < 1e-12
 
 
 # --------------------------------------------------------------------- #
