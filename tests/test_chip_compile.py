@@ -23,7 +23,8 @@ import repro.problems  # noqa: F401  (float64: enables jax x64)
 from repro.core import AndersonConfig, RunConfig, run_fixed_point
 from repro.core.anderson import AndersonState, _mix_kernel_auto
 from repro.kernels import ops
-from repro.problems import GarnetMDP, JacobiProblem, ValueIterationProblem
+from repro.problems import (GarnetMDP, HPCGProblem, JacobiProblem,
+                            ValueIterationProblem)
 
 
 @pytest.fixture(scope="module")
@@ -81,6 +82,30 @@ def test_jacobi_residual_norm_float64(one_chip):
     assert c.memory_analysis().output_size_in_bytes < g * 8
 
 
+def test_slab27_sweeps_float64(one_chip):
+    """HPCG's jnp z-slab step at 104^3 over 4 workers (26 planes of
+    104 x 104), in float64."""
+    from repro.problems.hpcg import _slab27_sweeps
+
+    planes, g = 26, 104
+    c = _compile(lambda b, t, o, bg: _slab27_sweeps(b, t, o, bg, 10),
+                 one_chip, ((planes, g, g), jnp.float64),
+                 ((g, g), jnp.float64), ((g, g), jnp.float64),
+                 ((planes, g, g), jnp.float64))
+    assert c.memory_analysis().output_size_in_bytes >= planes * g * g * 8
+
+
+def test_hpcg_residual_norm_float64(one_chip):
+    """The coordinator's record of HPCG at 104^3, in float64: one fused
+    program whose only output is the 0-d norm."""
+    from repro.problems.hpcg import _residual_norm27
+
+    g = 104
+    c = _compile(lambda x, b: _residual_norm27(x, b, g), one_chip,
+                 ((g ** 3,), jnp.float64), ((g ** 3,), jnp.float64))
+    assert c.memory_analysis().output_size_in_bytes < g * g * 8
+
+
 def test_vi_block_step_float64(one_chip):
     """The jnp value-iteration block step at 2**18 states of S = 2**20."""
     from repro.problems.value_iteration import _vi_block_step
@@ -122,6 +147,16 @@ def test_jacobi_sweep_float32(one_chip):
 # --------------------------------------------------------------------- #
 def test_pallas_device_plane_float64_raises():
     p = JacobiProblem(grid=16, sweeps=2)
+    with pytest.raises(ValueError, match="Mosaic lowers no 64-bit types"):
+        run_fixed_point(p, RunConfig(mode="async", executor="thread",
+                                     n_workers=2, max_updates=8,
+                                     device_plane="pallas"))
+
+
+def test_pallas_hpcg_plane_raises():
+    """HPCG has no Pallas kernel: its float64 plane raises with the reason
+    before any worker starts."""
+    p = HPCGProblem(grid=8, sweeps=2)
     with pytest.raises(ValueError, match="Mosaic lowers no 64-bit types"):
         run_fixed_point(p, RunConfig(mode="async", executor="thread",
                                      n_workers=2, max_updates=8,

@@ -1,4 +1,8 @@
-"""The paper's three testbeds: correctness against independent references."""
+"""The paper's three testbeds and HPCG's operator: correctness against
+independent references."""
+
+import importlib.util
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -14,6 +18,7 @@ from repro.core import (
 from repro.problems import (
     GarnetMDP,
     GridWorldMDP,
+    HPCGProblem,
     JacobiProblem,
     PolicyEvaluationProblem,
     PPPChain,
@@ -117,6 +122,131 @@ class TestJacobi:
             lambda v: _residual_norm(v, p._b_j, grid),
             jax.ShapeDtypeStruct((p.n,), np.float64))
         assert out.shape == () and out.dtype == np.float64
+
+
+# --------------------------------------------------------------------- #
+# HPCG's 27-point operator
+# --------------------------------------------------------------------- #
+def _hpcg_reference(grid: int, sweeps: int):
+    """The benchmark's plain numpy reference (``chipbench/problems/
+    hpcg3d.py``, which imports nothing of the program), in float64."""
+    path = (Path(__file__).resolve().parents[1] / "chipbench" / "problems"
+            / "hpcg3d.py")
+    spec = importlib.util.spec_from_file_location("hpcg3d_reference", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.Reference({"grid": grid, "sweeps": sweeps}, seed=0)
+
+
+class TestHPCG:
+    @pytest.mark.parametrize("grid", [8, 16])
+    def test_b_is_A_times_ones(self, grid):
+        p = HPCGProblem(grid=grid)
+        ref = _hpcg_reference(grid, 1)
+        np.testing.assert_array_equal(p._b, ref.b.ravel())
+        # interior 0, faces 9, edges 15, corners 19
+        assert sorted(set(p._b)) == [0.0, 9.0, 15.0, 19.0]
+        assert np.sum(p._b == 19.0) == 8
+        assert np.sum(p._b == 0.0) == (grid - 2) ** 3
+
+    @pytest.mark.parametrize("grid,workers", [(8, 4), (12, 3), (16, 4)])
+    def test_plane_and_host_path_agree_with_reference(self, grid, workers):
+        """Each z-slab's update, by the host ``block_update`` and by the
+        device plan's ``refresh`` + ``step`` (``jnp``), on a seeded random
+        iterate: bitwise equal to each other, within 1e-12 of numpy."""
+        p = HPCGProblem(grid=grid, sweeps=4)
+        ref = _hpcg_reference(grid, 4)
+        x = np.random.default_rng(grid).standard_normal(p.n)
+        for idx in p.default_blocks(workers):
+            host = p.block_update(x, idx)
+            plan = p.device_block_plan(idx, "jnp")
+            plane = grid * grid  # the halos are the two neighbouring planes
+            halos = [sl for sl in (slice(idx[0] - plane, idx[0]),
+                                   slice(idx[-1] + 1, idx[-1] + 1 + plane))
+                     if 0 <= sl.start and sl.stop <= p.n]
+            assert plan.needs == halos
+            plan.refresh(x[idx])
+            dev, sq = plan.step(*[np.copy(x[s]) for s in plan.needs])
+            np.testing.assert_array_equal(dev, host)
+            want = ref.block_step(x, idx)
+            assert np.max(np.abs(host - want)) <= 1e-12 * np.max(np.abs(want))
+            assert sq == pytest.approx(np.sum((dev - x[idx]) ** 2),
+                                       rel=1e-12)
+            ref_plan = p.device_block_plan(idx, "ref")
+            ref_plan.refresh(x[idx])
+            got, _ = ref_plan.step(*[np.copy(x[s]) for s in ref_plan.needs])
+            assert np.max(np.abs(got - want)) <= 1e-12 * np.max(np.abs(want))
+
+    def test_one_sweep_is_the_full_map_restricted(self):
+        p = HPCGProblem(grid=8, sweeps=1)
+        x = np.random.default_rng(3).standard_normal(p.n)
+        g = p.full_map(x)
+        ref = _hpcg_reference(8, 1)
+        np.testing.assert_allclose(g, ref.block_step(x, np.arange(p.n)),
+                                   rtol=0, atol=1e-13)
+        for idx in p.default_blocks(2):
+            np.testing.assert_array_equal(p.block_update(x, idx), g[idx])
+        part = np.arange(5, 100)  # not whole planes: one-sweep restriction
+        assert p.device_block_plan(part, "jnp") is None
+        np.testing.assert_array_equal(
+            HPCGProblem(grid=8, sweeps=5).block_update(x, part), g[part])
+
+    @pytest.mark.parametrize("x_kind", ["zeros", "random", "ones"])
+    def test_residual_norm_is_one_device_scalar(self, x_kind):
+        import jax
+
+        from repro.problems.hpcg import _residual_norm27
+
+        p = HPCGProblem(grid=12)
+        x = {"zeros": np.zeros(p.n),
+             "random": np.random.default_rng(5).standard_normal(p.n),
+             "ones": np.ones(p.n)}[x_kind]
+        got = p.residual_norm(x)
+        assert type(got) is float
+        scale = np.linalg.norm(p._b)  # the norm at x = 0
+        assert abs(got - _hpcg_reference(12, 1).residual_norm(x)) \
+            <= 1e-13 * max(got, scale)
+        assert abs(got - np.linalg.norm(p.residual(x))) \
+            <= 1e-13 * max(got, scale)
+        if x_kind == "ones":
+            assert got == 0.0 and p.residual_norm(p.exact_solution()) == 0.0
+        out = jax.eval_shape(lambda v: _residual_norm27(v, p._b_j, 12),
+                             jax.ShapeDtypeStruct((p.n,), np.float64))
+        assert out.shape == () and out.dtype == np.float64
+
+    def test_dependency_pattern_is_27_point(self):
+        p = HPCGProblem(grid=6)
+        counts = p.dependency_counts()
+        assert [len(p.dependency_indices(i)) for i in range(p.n)] \
+            == list(counts)
+        assert counts.max() == 27 and counts.min() == 8
+        # every dependency is a neighbour in each coordinate
+        i = 2 * 36 + 3 * 6 + 4
+        zyx = np.stack(np.unravel_index(p.dependency_indices(i), (6, 6, 6)))
+        assert np.all(np.abs(zyx - np.array([[2], [3], [4]])) <= 1)
+        assert coupling_density(p) < 0.2
+        assert block_internal_coupling(p, p.default_blocks(2)) > 0.8
+
+    def test_pallas_and_interpret_planes_raise(self):
+        p = HPCGProblem(grid=8)
+        for mode in ("pallas", "interpret"):
+            with pytest.raises(ValueError, match="64-bit"):
+                p.device_block_plan(p.default_blocks(2)[0], mode)
+
+    def test_async_thread_run_on_the_plane_reaches_tol(self):
+        """Through ``run_fixed_point`` on the thread executor, every
+        update a device-plane dispatch, with the executor's ``block_eval``
+        (``path`` plane) and ``record`` spans, to an error below 1e-5."""
+        p = HPCGProblem(grid=8, sweeps=5)
+        res = run_fixed_point(p, RunConfig(
+            mode="async", executor="thread", n_workers=4, tol=1e-8,
+            max_updates=20000, device_plane="jnp", telemetry=True))
+        assert res.converged and res.error_norm < 1e-5
+        assert res.device_dispatches == res.worker_updates > 0
+        evals = [e for e in res.telemetry.events if e["k"] == "block_eval"]
+        assert evals and {e["path"] for e in evals} == {"plane"}
+        records = [e for e in res.telemetry.events if e["k"] == "record"]
+        assert len(records) == len(res.history)
 
 
 # --------------------------------------------------------------------- #
