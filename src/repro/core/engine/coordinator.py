@@ -412,6 +412,11 @@ class Coordinator:
         self.last_apply_verbatim = False
         self.device_dispatches = 0
         self.device_refreshes = 0
+        self.device_applies = 0  # arrivals written into x_dev
+        # The iterate on the device (a DeviceIterate), where the executor
+        # put the run there (attach_device_iterate): it then holds the
+        # current values, and x is stale until host_sync().
+        self.x_dev = None
         # Last fused block-local residual norm per worker (a convergence
         # proxy for observability; the recorded history stays the true
         # full residual).
@@ -855,7 +860,10 @@ class Coordinator:
             # storing them is safe.
             for p in self._pin_watch:
                 p._pin_saves.append((ind, np.copy(self.x[ind])))
-        if cfg.block_damping is not None:
+        if self.x_dev is not None:
+            self.x_dev.write(ind, values)
+            self.device_applies += 1
+        elif cfg.block_damping is not None:
             a = cfg.block_damping
             self.x[ind] = (1.0 - a) * self.x[ind] + a * values
             verbatim = False
@@ -1352,7 +1360,8 @@ class Coordinator:
             # history and series keep the caller's t.
             t_h0 = time.perf_counter()
             sec = tel.section("record", "coord").open()
-        self.res_norm = self.problem.residual_norm(self.x)
+        self.res_norm = self.problem.residual_norm(
+            self.x if self.x_dev is None else self.x_dev.x)
         if tel is not None:
             sec.close(res=self.res_norm, wu=self.wu)
             tel.host_busy_s += time.perf_counter() - t_h0
@@ -1364,6 +1373,41 @@ class Coordinator:
             tel.series_point("residual", t, self.res_norm)
             tel.maybe_sample_busy(t, self.busy_s)
         return self.res_norm
+
+    # ----------------------------------------------------------------- #
+    # The device iterate (device-resident runs)
+    # ----------------------------------------------------------------- #
+    def attach_device_iterate(self) -> None:
+        """Hold the iterate on the device from here on: one upload of x.
+
+        Arrivals then take device blocks and write them there, dispatches
+        :meth:`take` device slices, and records read it.  The executor
+        attaches it only where no arrival needs host values
+        (``device_plane.resident_iterate``); nothing may read ``x`` until
+        :meth:`host_sync`, which :meth:`result` runs."""
+        from .device_iterate import DeviceIterate
+
+        self.x_dev = DeviceIterate(self.x)
+
+    def take(self, s):
+        """The iterate at ``s`` (a slice, a dependency index array or one
+        of ``blocks``) for a dispatch: a device array where the iterate is
+        on the device, else a host copy."""
+        s = self._block_slices.get(id(s), s)
+        if self.x_dev is None:
+            return np.copy(self.x[s])
+        return self.x_dev.take(s)
+
+    def host_sync(self) -> None:
+        """Read the device iterate back into ``x`` and detach it: once a
+        solve, as the telemetry section ``host_sync``."""
+        tel = self.telemetry
+        if tel is not None:
+            sec = tel.section("host_sync", "coord").open()
+        self.x = self.x_dev.to_host()
+        self.x_dev = None
+        if tel is not None:
+            sec.close()
 
     def record_begin(self, t: float) -> RecordPlan:
         """Open an offloaded residual record at the current iterate."""
@@ -1399,6 +1443,8 @@ class Coordinator:
         return self.res_norm < self.cfg.tol
 
     def result(self, t: float, rounds: int, converged: bool) -> RunResult:
+        if self.x_dev is not None:
+            self.host_sync()
         mean_stale = self.staleness_sum / max(self.staleness_n, 1)
         acc = self.accel
         if self.probe is not None:  # close the worker-seconds meter at t
@@ -1465,6 +1511,7 @@ class Coordinator:
             pin_cow_saves=self.pin_cow_saves,
             device_dispatches=self.device_dispatches,
             device_refreshes=self.device_refreshes,
+            device_applies=self.device_applies,
             trace=(self.tracer.to_trace() if self.tracer is not None
                    else None),
             telemetry=tel_capture,

@@ -36,6 +36,12 @@ lowers no general gather); see :mod:`repro.kernels.ops`.
 ``"auto"`` (the default) additionally requires ``n >= AUTO_THRESHOLD``:
 below it the halo savings don't pay for the host<->device hops, above it
 the O(n) snapshot per dispatch is the dominant cost the plane removes.
+
+:func:`resident_iterate` decides, for a thread-backend run on the plane,
+whether the coordinator's iterate can live on the device too
+(``Coordinator.attach_device_iterate``): where no arrival needs host
+values, block returns stay on the device, halos and refreshes slice the
+device iterate, and the record reads it.
 """
 
 from __future__ import annotations
@@ -43,9 +49,9 @@ from __future__ import annotations
 from typing import Optional
 
 from ..fixedpoint import FixedPointProblem
-from .types import RunConfig
+from .types import RunConfig, _fault_for
 
-__all__ = ["AUTO_THRESHOLD", "resolve_device_plane"]
+__all__ = ["AUTO_THRESHOLD", "resident_iterate", "resolve_device_plane"]
 
 #: "auto" flips the device plane on at this state size (n = 2**20: the
 #: per-dispatch O(n) snapshot crosses ~8 MB, which is where BENCH_hotpath
@@ -81,3 +87,18 @@ def resolve_device_plane(problem: FixedPointProblem, cfg: RunConfig,
     if mode == "auto":
         return "jnp" if problem.n >= AUTO_THRESHOLD else None
     return "jnp" if mode == "on" else mode
+
+
+def resident_iterate(cfg: RunConfig) -> bool:
+    """Whether no arrival of this run needs the block's host values.
+
+    Anderson mixes the whole iterate on the host, noise and corruption
+    rewrite a return there, the SDC screen and damping read ``x[block]``,
+    and ``converge_on="error"`` reads the whole iterate at every record;
+    any of them keeps the iterate on the host.  Only for runs the
+    resolver already put on the plane with a plan for every block."""
+    profiles = [_fault_for(cfg, w) for w in range(cfg.n_workers)]
+    return (cfg.accel is None and not cfg.sdc_guard
+            and cfg.block_damping is None and cfg.converge_on == "residual"
+            and all(p.noise_std <= 0.0 and p.corrupt_prob <= 0.0
+                    for p in profiles))

@@ -312,6 +312,7 @@ def _worker_main(
                 if blk is not None:
                     dplan.refresh(blk)
                 vals, dnorm = dplan.step(*needs)
+                vals = np.asarray(vals)  # shipped through shared memory
                 tel_note("compute", c0)
             else:
                 _, idx = task

@@ -34,7 +34,7 @@ from typing import Optional
 
 import numpy as np
 
-from ..fixedpoint import FixedPointProblem
+from ..fixedpoint import FixedPointProblem, as_block_slice
 from .base import Executor, register_executor
 from .coordinator import (
     LAZY_PIN_MIN_N,
@@ -198,6 +198,25 @@ class ThreadPoolExecutor(Executor):
         # rng (drop/noise/selection) behind the lock and everything else out.
         seeds = np.random.SeedSequence(cfg.seed).spawn(cfg.n_workers)
         worker_rngs = [np.random.default_rng(s) for s in seeds]
+        # Device-resident data plane (cfg.device_plane): when the run
+        # shape qualifies, each worker keeps its block resident as a
+        # device array and per dispatch ships only the halo/dependency
+        # slices its update reads — the O(n) snapshot copy and full-x
+        # transfer disappear from the hot loop.  Resolution is structural
+        # (see engine.device_plane); problems opt in per block.  Where
+        # every block has a plan and no arrival needs host values, the
+        # iterate itself lives on the device too: returns stay there and
+        # halos, refreshes and records read the device iterate.
+        from .device_plane import resident_iterate, resolve_device_plane
+
+        dmode = resolve_device_plane(problem, cfg, self.name)
+        dplans = {}
+        if dmode is not None:
+            for dw in range(cfg.n_workers):
+                dp = problem.device_block_plan(coord.blocks[dw], dmode)
+                if dp is not None:
+                    dplans[dw] = dp
+        resident = len(dplans) == cfg.n_workers and resident_iterate(cfg)
         if cfg.resume_from is not None:
             # Reconstruct a checkpointed solve: the coordinator (and with
             # it the iterate, rng, Anderson window, counters) restores from
@@ -221,6 +240,8 @@ class ThreadPoolExecutor(Executor):
             t0 = time.perf_counter() - ckpt.t
         else:
             t0 = time.perf_counter()
+            if resident:
+                coord.attach_device_iterate()
             coord.record(0.0)
 
         def elapsed() -> float:
@@ -234,32 +255,22 @@ class ThreadPoolExecutor(Executor):
             return ({"kind": "thread_async",
                      "since_fire": state["since_fire"]}, {})
 
-        # Device-resident data plane (cfg.device_plane): when the run
-        # shape qualifies, each worker keeps its block resident as a
-        # device array and per dispatch ships only the halo/dependency
-        # slices its update reads — the O(n) snapshot copy and full-x
-        # transfer disappear from the hot loop.  Resolution is structural
-        # (see engine.device_plane); problems opt in per block.
-        from .device_plane import resolve_device_plane
-
-        dmode = resolve_device_plane(problem, cfg, self.name)
-        dplans = {}
-        if dmode is not None:
-            for dw in range(cfg.n_workers):
-                dp = problem.device_block_plan(coord.blocks[dw], dmode)
-                if dp is not None:
-                    dplans[dw] = dp
-            if dplans:
-                # Warm the fused-kernel specializations before the workers
-                # start (mirrors warm_problem for the host path).
-                if tel is not None:
-                    sec = tel.section("warm", "coord").open()
-                zx = np.zeros(problem.n)
-                for dw, dp in dplans.items():
-                    dp.refresh(zx[coord.blocks[dw]])
-                    dp.step(*[zx[s] for s in dp.needs])
-                if tel is not None:
-                    sec.close()
+        if dplans:
+            # Warm the fused-kernel specializations before the workers
+            # start (mirrors warm_problem for the host path), and where the
+            # iterate is on the device its slices and block write: each
+            # block is written back unchanged.
+            if tel is not None:
+                sec = tel.section("warm", "coord").open()
+            for dw, dp in dplans.items():
+                blk_vals = coord.take(coord.blocks[dw])
+                dp.refresh(blk_vals)
+                dp.step(*[coord.take(s) for s in dp.needs])
+                if resident:
+                    coord.x_dev.write(as_block_slice(coord.blocks[dw]),
+                                      blk_vals)
+            if tel is not None:
+                sec.close()
 
         def worker_loop(w: int) -> None:
             prof = _fault_for(cfg, w)
@@ -296,12 +307,13 @@ class ThreadPoolExecutor(Executor):
                     if dp is not None:
                         # Fresh resident block: ship only the halo slices
                         # (O(needs)); stale: re-ship the block (O(block)).
-                        # Never the full iterate.
+                        # Never the full iterate.  Device slices where the
+                        # iterate is on the device.
                         blk_vals = None
                         if not (dev_fresh
                                 and coord.commit_version == dev_cver):
-                            blk_vals = np.copy(coord.x[idx])
-                        need_vals = [np.copy(coord.x[s]) for s in dp.needs]
+                            blk_vals = coord.take(idx)
+                        need_vals = [coord.take(s) for s in dp.needs]
                     else:
                         x_snap = coord.x.copy()
                 if tel is not None:
@@ -313,6 +325,8 @@ class ThreadPoolExecutor(Executor):
                     if blk_vals is not None:
                         dp.refresh(blk_vals)
                     vals, dev_norm = dp.step(*need_vals)
+                    if not resident:
+                        vals = np.asarray(vals)  # the host iterate's copy
                 else:
                     vals = worker_eval(problem, cfg, x_snap, idx)
                 if tel is not None:

@@ -313,7 +313,8 @@ class RunResult:
     # eager O(n) begin-time copy.
     pin_cow_saves: int = 0
     device_dispatches: int = 0  # block updates served by the device plane
-    device_refreshes: int = 0  # device blocks re-synced from the host iterate
+    device_refreshes: int = 0  # blocks re-synced from the coordinator's x
+    device_applies: int = 0  # arrivals written on the device, no host copy
     # --- trace capture (cfg.capture_trace) -------------------------------- #
     trace: Optional[object] = None  # repro.chaos.RunTrace
     # --- telemetry plane (cfg.telemetry) ----------------------------------- #

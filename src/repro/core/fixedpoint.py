@@ -36,22 +36,25 @@ class DeviceBlockPlan:
     dependency closures) instead of re-materializing the full iterate:
 
     * ``needs`` — list of ``slice`` objects (or sorted index arrays, for
-      dependency closures) into the flat iterate whose current host
-      values ``step`` consumes each dispatch;
+      dependency closures) into the flat iterate whose current values
+      ``step`` consumes each dispatch;
     * ``refresh(block_values)`` — (re)load the resident block from host
-      values (after an accel commit or a non-verbatim apply);
+      or device values (after an accel commit or a non-verbatim apply);
     * ``step(*need_vals)`` — run one fused block update on the resident
-      block, advance it in place, and return ``(values, local_norm)``
-      where ``values`` is the host copy for ``apply_return`` and
-      ``local_norm`` the kernel's fused block-local residual norm.
+      block (``need_vals`` host or device arrays), advance it in place,
+      and return ``(values, local_norm)`` where ``values`` is the new
+      flat block as a device array (no host copy: a device-resident
+      coordinator writes it on the device, any other caller takes
+      ``np.asarray``) and ``local_norm`` the kernel's fused block-local
+      residual norm, a float.
     """
 
     needs: List[slice] = []
 
-    def refresh(self, block_values: np.ndarray) -> None:
+    def refresh(self, block_values) -> None:
         raise NotImplementedError
 
-    def step(self, *need_vals: np.ndarray):
+    def step(self, *need_vals):
         raise NotImplementedError
 
 

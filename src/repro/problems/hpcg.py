@@ -127,12 +127,11 @@ class _Slab27Plan(DeviceBlockPlan):
         ) if s is not None]
         self._blk = None
 
-    def refresh(self, block_values: np.ndarray) -> None:
-        self._blk = jnp.asarray(np.asarray(block_values, dtype=np.float64)
-                                .reshape(self._z1 - self._z0, self._g,
-                                         self._g))
+    def refresh(self, block_values) -> None:
+        self._blk = jnp.asarray(block_values, dtype=jnp.float64).reshape(
+            self._z1 - self._z0, self._g, self._g)
 
-    def step(self, *need_vals: np.ndarray):
+    def step(self, *need_vals):
         g, halos = self._g, iter(need_vals)
         top = (jnp.asarray(next(halos).reshape(g, g)) if self._z0 > 0
                else self._zeros)
@@ -147,7 +146,7 @@ class _Slab27Plan(DeviceBlockPlan):
                 np.asarray(self._bg), self._sweeps)
             new = jnp.asarray(new)
         self._blk = new
-        return np.asarray(new).ravel(), float(norm)
+        return new.reshape(-1), float(norm)
 
 
 @functools.partial(jax.jit, static_argnames=("g",))

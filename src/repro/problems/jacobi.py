@@ -118,12 +118,11 @@ class _JacobiDevicePlan(DeviceBlockPlan):
 
             self._band_mesh = band_mesh(self._rows)
 
-    def refresh(self, block_values: np.ndarray) -> None:
-        self._blk = jnp.asarray(
-            np.asarray(block_values, dtype=np.float64).reshape(
-                self._rows, self._g))
+    def refresh(self, block_values) -> None:
+        self._blk = jnp.asarray(block_values, dtype=jnp.float64).reshape(
+            self._rows, self._g)
 
-    def step(self, *need_vals: np.ndarray):
+    def step(self, *need_vals):
         halos = iter(need_vals)
         top = jnp.asarray(next(halos)) if self._r0 > 0 else self._zeros
         bot = jnp.asarray(next(halos)) if self._r1 < self._g else self._zeros
@@ -154,7 +153,7 @@ class _JacobiDevicePlan(DeviceBlockPlan):
         else:
             raise ValueError(f"unknown device_plane mode {self._mode!r}")
         self._blk = new
-        return np.asarray(new).ravel(), float(norm)
+        return new.reshape(-1), float(norm)
 
 
 @functools.partial(jax.jit, static_argnames=("g",))

@@ -184,10 +184,10 @@ class _VIDevicePlan(DeviceBlockPlan):
         self._R = mdp.R[s0:s1]
         self._blk = None
 
-    def refresh(self, block_values: np.ndarray) -> None:
-        self._blk = jnp.asarray(np.asarray(block_values, dtype=np.float64))
+    def refresh(self, block_values) -> None:
+        self._blk = jnp.asarray(block_values, dtype=jnp.float64)
 
-    def step(self, *need_vals: np.ndarray):
+    def step(self, *need_vals):
         v = jnp.asarray(need_vals[0])
         if self._mode == "jnp":
             tv, norm = _vi_block_step(v, self._blk, self._remap,
@@ -209,7 +209,7 @@ class _VIDevicePlan(DeviceBlockPlan):
         else:
             raise ValueError(f"unknown device_plane mode {self._mode!r}")
         self._blk = tv
-        return np.asarray(tv), float(norm)
+        return tv, float(norm)
 
 
 def _policy_iteration(idx, probs, R, gamma: float,

@@ -84,6 +84,8 @@ SPAN_KINDS: Dict[str, str] = {
     "apply": "a thread worker's arrival under the coordinator lock: "
              "apply_return and its bookkeeping, up to the arrival tick",
     "warm": "the thread executor's warm-up of the run's compiled shapes",
+    "host_sync": "the one read-back of a device-resident iterate into the "
+                 "host's, after the solve",
     "eval": "one offloaded evaluation item (full-map or residual norm) "
             "served by a worker/eval thread",
     "checkpoint": "checkpoint capture + atomic write",

@@ -106,6 +106,23 @@ def test_hpcg_residual_norm_float64(one_chip):
     assert c.memory_analysis().output_size_in_bytes < g * g * 8
 
 
+@pytest.mark.parametrize("n, block", [(2800 ** 2, 700 * 2800),
+                                      (104 ** 3, 26 * 104 ** 2)],
+                         ids=["jacobi_g2800", "hpcg_n104"])
+def test_device_iterate_block_write_float64(one_chip, n, block):
+    """A device-resident arrival over 4 workers, in float64: one block
+    written into the whole iterate at an offset known at run time, and the
+    slice a refresh takes back out of it."""
+    from repro.core.engine.device_iterate import _slice, _write
+
+    c = _compile(_write, one_chip, ((n,), jnp.float64),
+                 ((block,), jnp.float64), ((), jnp.int64))
+    assert c.memory_analysis().output_size_in_bytes >= n * 8
+    c = _compile(lambda x, s: _slice(x, s, size=block), one_chip,
+                 ((n,), jnp.float64), ((), jnp.int64))
+    assert c.memory_analysis().output_size_in_bytes >= block * 8
+
+
 def test_vi_block_step_float64(one_chip):
     """The jnp value-iteration block step at 2**18 states of S = 2**20."""
     from repro.problems.value_iteration import _vi_block_step

@@ -25,6 +25,7 @@ from repro.core.engine.process import ProcessPoolExecutor
 from repro.core.engine.threadpool import ThreadPoolExecutor
 from repro.core.engine.types import FaultProfile, RunConfig
 from repro.core.engine.virtual_time import VirtualTimeExecutor
+from repro.problems.hpcg import HPCGProblem
 from repro.problems.jacobi import JacobiProblem
 
 RNG = np.random.default_rng(42)
@@ -200,6 +201,57 @@ class TestExecutorLoops:
         # every apply is non-verbatim => every dispatch after the first
         # reships the block
         assert res.device_refreshes >= res.device_dispatches - cfg.n_workers
+        self._converges(res, p)
+
+
+# --------------------------------------------------------------------- #
+# the device-resident iterate: returns, halos and records on the device
+# --------------------------------------------------------------------- #
+class TestResidentIterate:
+    def _converges(self, res, p):
+        assert p.residual_norm(res.x) < 0.5 * p.residual_norm(p.initial())
+
+    @pytest.mark.parametrize("make", [lambda: JacobiProblem(grid=64),
+                                      lambda: HPCGProblem(grid=16)],
+                             ids=["jacobi", "hpcg"])
+    def test_one_worker_run_matches_the_host_path(self, make):
+        """One worker: the same trajectory bit for bit, with the iterate
+        on the device and with it on the host."""
+        p = make()
+        runs = {mode: ThreadPoolExecutor().run(p, _cfg(
+            device_plane=mode, n_workers=1, max_updates=30, tol=1e-300))
+            for mode in ("jnp", "off")}
+        on, off = runs["jnp"], runs["off"]
+        assert on.device_applies == on.worker_updates == 30
+        assert off.device_applies == off.device_dispatches == 0
+        np.testing.assert_array_equal(on.x, off.x)
+        assert on.residual_norm == off.residual_norm
+        assert [h[1:] for h in on.history] == [h[1:] for h in off.history]
+
+    def test_four_worker_run_records_its_own_iterate(self):
+        p = JacobiProblem(grid=32, sweeps=3)
+        res = ThreadPoolExecutor().run(p, _cfg(
+            device_plane="jnp", n_workers=4, max_updates=120, seed=1))
+        # every applied arrival was written on the device, none on the host
+        assert res.device_applies == res.worker_updates >= 120
+        assert res.device_refreshes < res.device_dispatches
+        assert res.residual_norm == pytest.approx(p.residual_norm(res.x),
+                                                  rel=1e-12)
+        assert res.x.flags.writeable
+        self._converges(res, p)
+
+    @pytest.mark.parametrize("kw", [
+        dict(accel=AndersonConfig(m=3), fire_every=20),
+        dict(faults=FaultProfile(noise_std=1e-9)),
+        dict(sdc_guard=True),
+        dict(block_damping=0.8),
+        dict(converge_on="error"),
+    ], ids=["accel", "noise", "sdc_guard", "damping", "error"])
+    def test_host_values_keep_the_iterate_on_the_host(self, kw):
+        p = JacobiProblem(grid=32, sweeps=3)
+        res = ThreadPoolExecutor().run(p, _cfg(
+            device_plane="jnp", max_updates=80, seed=2, **kw))
+        assert res.device_dispatches > 0 and res.device_applies == 0
         self._converges(res, p)
 
 

@@ -508,6 +508,21 @@ class TestThreadSections:
         assert not _spans(res, "lock_wait")  # the sync loop has no lock
 
     @pytest.mark.parametrize("mode", ["async", "sync"])
+    def test_the_device_iterate_is_read_back_once(self, thread_runs, mode):
+        """Async on the plane: every return is written on the device, and
+        the host iterate is read back once, after the final record.  Sync
+        keeps its iterate on the host."""
+        res = thread_runs[mode]
+        syncs = _spans(res, "host_sync")
+        if mode == "sync":
+            assert not syncs and res.device_applies == 0
+            return
+        (sync,) = syncs
+        assert sync["lane"] == "coord"
+        assert sync["t0"] >= max(e["t1"] for e in _spans(res, "record"))
+        assert res.device_applies == res.worker_updates > 0
+
+    @pytest.mark.parametrize("mode", ["async", "sync"])
     def test_straggler_delay_spans(self, thread_runs, mode):
         res = thread_runs[mode]
         delays = _spans(res, "delay")
@@ -544,8 +559,10 @@ class TestThreadSections:
     def test_spans_lie_on_one_clock(self, thread_runs, mode):
         res = thread_runs[mode]
         spans = [e for e in res.telemetry.events if "t0" in e]
-        # async: the final record follows the stop at wall_time
-        end = max([res.wall_time] + [e["t1"] for e in _spans(res, "record")])
+        # async: the final record follows the stop at wall_time, and the
+        # device iterate's read-back follows it
+        end = max([res.wall_time] + [e["t1"] for e in _spans(res, "record")]
+                  + [e["t1"] for e in _spans(res, "host_sync")])
         for e in spans:
             if e["k"] != "warm":
                 assert 0.0 <= e["t0"] <= e["t1"] <= end, e
@@ -582,7 +599,8 @@ class TestThreadSections:
                         (plane.name, line.name))
         (window_line,) = names["window"]
         want = {ANNOTATION_PREFIX + k
-                for k in ("record", "block_eval", "delay", "apply", "warm")}
+                for k in ("record", "block_eval", "delay", "apply", "warm",
+                          "host_sync")}
         assert ANNOTATION_PREFIX == "solver."
         for name in want:
             assert window_line in names.get(name, ()), name
